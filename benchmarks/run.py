@@ -10,6 +10,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 MODULES = [
     "fig1_ber",
     "fig4_bitlevel",
@@ -35,6 +37,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    enable_compile_cache()
     only = [s.strip() for s in args.only.split(",") if s.strip()]
 
     failures = []

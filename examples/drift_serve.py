@@ -65,6 +65,7 @@ import contextlib
 
 from repro.core import dvfs as dvfs_lib
 from repro.core.rollback import DEFAULT_INTERVAL
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import (arch_family_help, default_mode_for,
                                 rollback_interval_arg)
 from repro.serving import (DeadlineScheduler, DriftServeEngine,
@@ -84,6 +85,10 @@ def build_parser():
     ap.add_argument("--arch", default="dit-xl-512",
                     help="model to serve; paradigm comes from the "
                          f"ServableModel registry -- {arch_family_help()}")
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the arch's small smoke config; --no-smoke "
+                         "serves its published widths")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--steps", type=int, default=10)
@@ -146,6 +151,7 @@ def build_parser():
 
 def main():
     args = build_parser().parse_args()
+    enable_compile_cache()
 
     ops = [o.strip() for o in args.op.split(",") if o.strip()]
     priorities = [p.strip() for p in args.priority.split(",") if p.strip()]
@@ -162,14 +168,14 @@ def main():
     telemetry = EngineTelemetry(enabled=not args.no_telemetry)
     offload = OffloadConfig() if args.offload else None
     if args.sharded:
-        engine = make_engine(arch=args.arch, smoke=True,
+        engine = make_engine(arch=args.arch, smoke=args.smoke,
                              bucket=args.batch,
                              model_parallel=args.model_parallel,
                              telemetry=telemetry, offload=offload)
     else:
         if args.model_parallel != 1:
             raise SystemExit("--model-parallel requires --sharded")
-        engine = DriftServeEngine(arch=args.arch, smoke=True,
+        engine = DriftServeEngine(arch=args.arch, smoke=args.smoke,
                                   bucket=args.batch, telemetry=telemetry,
                                   offload=offload)
     server = None

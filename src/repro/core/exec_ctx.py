@@ -48,7 +48,6 @@ class DriftSystemConfig:
     protect_attention_gemms: bool = False   # also wrap QK^T / AV batched GEMMs
     double_flip: bool = False
     force_bit: int = -1                     # pin flipped bit (Sec 4.1 sweeps)
-    backend: str = "jnp"                    # "jnp" | "pallas" (interpret on CPU)
 
     def __post_init__(self):
         assert self.mode in MODES, self.mode

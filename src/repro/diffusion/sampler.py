@@ -189,7 +189,11 @@ def _init_carry(model_cfg: ModelConfig, params, latents0, cond, text,
                           cfg.drift)
     taylor0 = ts_lib.init_state(latents0.shape)
     mon0 = monitor0 if monitor0 is not None else dvfs_lib.ber_monitor_init()
-    return (latents0, stores0, taylor0, mon0, jnp.int32(0), jnp.int32(0))
+    # The corrected-element total is carried in float32: one step's count
+    # fits int32, but a full-width batch's does not (DiT-XL/2 at bucket 4
+    # rolls back about 2e10 elements in 20 undervolted steps, which wraps
+    # int32). Exact up to 2^24, then to about 1 part in 10^7 per step.
+    return (latents0, stores0, taylor0, mon0, jnp.float32(0), jnp.int32(0))
 
 
 def _make_step_fn(model_cfg: ModelConfig, cfg: SamplerConfig, sched,
@@ -249,7 +253,8 @@ def _make_step_fn(model_cfg: ModelConfig, cfg: SamplerConfig, sched,
         # The per-site detection vector rides the scan's ys slot: stacked
         # over steps it becomes the (steps, rows) resilience heatmap.
         return (new_latents, stores2, taylor2, mon2,
-                corrected + corr, nevals + ran), det_blocks
+                corrected + corr.astype(jnp.float32), nevals + ran), \
+            det_blocks
 
     return step_fn
 

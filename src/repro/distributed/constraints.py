@@ -71,6 +71,8 @@ class MeshPolicy:
             return P(dp, *mid, "model")
         if kind == "batch_only":
             return P(dp, *([None] * (len(shape) - 1)))
+        if kind == "replicated":  # whole array on every device
+            return P(*([None] * len(shape)))
         if kind == "tokens2d":   # (T, d) flattened token streams (MoE)
             return P(dp, None)
         if kind == "slots2d":    # (E*C, d) expert-major flat slot space
@@ -78,8 +80,9 @@ class MeshPolicy:
             if shape[0] % max(msize, 1) == 0 and msize > 1:
                 return P("model", None)
             return None
-        if kind == "w2d_model":  # (K, N) int8 weights: gathered over data,
-            # output dim on model (the DRIFT quantized-GEMM layout)
+        if kind == "w2d_model":  # (K, N) GEMM weights whose K must not
+            # be split: gathered over data, output dim on model (the DRIFT
+            # quantized-GEMM layout; also the DiT adaLN modulation)
             msize = axis_size(self.mesh, "model")
             if len(shape) == 2 and shape[1] % max(msize, 1) == 0 and msize > 1:
                 return P(None, "model")

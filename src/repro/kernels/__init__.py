@@ -1,22 +1,5 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
-"""Cross-version Pallas compat helpers shared by the TPU kernels."""
-from __future__ import annotations
+"""Pallas TPU kernels and their pure-jnp oracles (``ref.py``).
 
-from jax.experimental.pallas import tpu as pltpu
-
-
-def tpu_compiler_params(*, dimension_semantics=None, **kwargs):
-    """Build TPU compiler params across the JAX API rename.
-
-    Newer JAX exposes ``pltpu.CompilerParams``; older releases (<= 0.4.x)
-    call the same structure ``pltpu.TPUCompilerParams``. Resolve whichever
-    the installed JAX provides so the kernels import everywhere.
-    """
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = getattr(pltpu, "TPUCompilerParams")
-    if dimension_semantics is not None:
-        kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    return cls(**kwargs)
+Every kernel takes ``interpret`` explicitly: ``False`` (the default)
+compiles it with Mosaic for the TPU; CPU tests pass ``interpret=True``.
+"""

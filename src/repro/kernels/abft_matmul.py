@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _kernel(a_ref, b_ref, flip_ref,
             c_ref, act_row_ref, exp_row_ref, act_col_ref, exp_col_ref,
@@ -52,15 +50,14 @@ def _kernel(a_ref, b_ref, flip_ref,
     # Fused checksum lanes: expected row sums need B's block row-sum vector,
     # expected col sums need A's block col-sum vector -- both rank-1, so the
     # extra work is one MAC column + one MAC row per tile (the "+1 lane" of
-    # the ABFT-wrapped systolic array).
-    b_rowsum = jnp.sum(b32, axis=1, keepdims=True)        # (bk, 1)
-    a_colsum = jnp.sum(a32, axis=0, keepdims=True)        # (1, bk)
-    exp_row_acc[...] += jax.lax.dot_general(
-        a32, b_rowsum, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)                  # (bm, 1)
-    exp_col_acc[...] += jax.lax.dot_general(
-        a_colsum, b32, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)                  # (1, bn)
+    # the ABFT-wrapped systolic array). The MXU takes no int32 operands, so
+    # the lanes are broadcast-multiply-reduce passes on the vector unit.
+    b_rowsum = jnp.sum(b32.T, axis=0, keepdims=True)      # (1, bk)
+    a_colsum = jnp.sum(a32.T, axis=1, keepdims=True)      # (bk, 1)
+    exp_row_acc[...] += jnp.sum(a32 * b_rowsum, axis=1,
+                                keepdims=True)            # (bm, 1)
+    exp_col_acc[...] += jnp.sum(a_colsum * b32, axis=0,
+                                keepdims=True)            # (1, bn)
 
     @pl.when(k == n_k - 1)
     def _finalize():
@@ -91,12 +88,15 @@ def abft_matmul(aq: jax.Array, bq: jax.Array, flips: jax.Array,
     mt, nt, kt = m // bm, n // bn, k // bk
 
     grid = (mt, nt, kt)
+    # Checksums carry a leading tile axis so every block's last two dims
+    # are (8, 128)-aligned or span the array: (bm, 1) of (nt, M, 1) and
+    # (1, bn) of (mt, 1, N), where (bm, 1) of (M, Nt) would be refused.
     out_shapes = (
         jax.ShapeDtypeStruct((m, n), jnp.int32),        # c_faulty
-        jax.ShapeDtypeStruct((m, nt), jnp.int32),       # act_row
-        jax.ShapeDtypeStruct((m, nt), jnp.int32),       # exp_row
-        jax.ShapeDtypeStruct((mt, n), jnp.int32),       # act_col
-        jax.ShapeDtypeStruct((mt, n), jnp.int32),       # exp_col
+        jax.ShapeDtypeStruct((nt, m, 1), jnp.int32),    # act_row
+        jax.ShapeDtypeStruct((nt, m, 1), jnp.int32),    # exp_row
+        jax.ShapeDtypeStruct((mt, 1, n), jnp.int32),    # act_col
+        jax.ShapeDtypeStruct((mt, 1, n), jnp.int32),    # exp_col
     )
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -105,24 +105,27 @@ def abft_matmul(aq: jax.Array, bq: jax.Array, flips: jax.Array,
     ]
     out_specs = (
         pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        pl.BlockSpec((bm, 1), lambda i, j, kk: (i, j)),
-        pl.BlockSpec((bm, 1), lambda i, j, kk: (i, j)),
-        pl.BlockSpec((1, bn), lambda i, j, kk: (i, j)),
-        pl.BlockSpec((1, bn), lambda i, j, kk: (i, j)),
+        pl.BlockSpec((None, bm, 1), lambda i, j, kk: (j, i, 0)),
+        pl.BlockSpec((None, bm, 1), lambda i, j, kk: (j, i, 0)),
+        pl.BlockSpec((None, 1, bn), lambda i, j, kk: (i, 0, j)),
+        pl.BlockSpec((None, 1, bn), lambda i, j, kk: (i, 0, j)),
     )
     scratch = [
         pltpu.VMEM((bm, bn), jnp.int32),
         pltpu.VMEM((bm, 1), jnp.int32),
         pltpu.VMEM((1, bn), jnp.int32),
     ]
-    return pl.pallas_call(
+    c, act_row, exp_row, act_col, exp_col = pl.pallas_call(
         functools.partial(_kernel, n_k=kt),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
         scratch_shapes=scratch,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(aq, bq, flips)
+    rows = lambda x: x[..., 0].T                       # (nt, M, 1) -> (M, Nt)
+    cols = lambda x: x[:, 0, :]                        # (mt, 1, N) -> (Mt, N)
+    return c, rows(act_row), rows(exp_row), cols(act_col), cols(exp_col)

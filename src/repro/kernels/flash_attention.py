@@ -15,14 +15,11 @@ Mosaic with MXU-aligned (128, 128) default tiles.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels import tpu_compiler_params
 
 NEG_INF = -2.0e38
 
@@ -71,10 +68,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False,
                     bq: int = 128, bk: int = 128,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """q, k, v: (BH, S, D) -> (BH, S, D). S % bq == S % bk == 0."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     bh, s, d = q.shape
     assert s % bq == 0 and s % bk == 0, (s, bq, bk)
     nq, nk = s // bq, s // bk
@@ -96,7 +91,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -104,7 +99,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def mha_flash(q: jax.Array, k: jax.Array, v: jax.Array,
               causal: bool = False, bq: int = 128, bk: int = 128,
-              interpret: Optional[bool] = None) -> jax.Array:
+              interpret: bool = False) -> jax.Array:
     """(B, S, H, D) convenience wrapper (no GQA: repeat KV before calling)."""
     b, s, h, d = q.shape
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, s, d)

@@ -2,12 +2,10 @@
 
 ``drift_gemm``: quantize -> fused faulty-ABFT GEMM (Pallas) -> dequantize ->
 rollback correction (Pallas). Pure function of (x, w, ckpt, key, ber);
-this is the path ExecContext(backend="pallas") dispatches to, and the unit
-the kernel tests sweep against the ref.py oracles.
+the unit the kernel tests sweep against the ref.py oracles.
 
-On CPU (this container) the kernels run with interpret=True; on TPU the same
-code path compiles to Mosaic. ``interpret`` defaults to True when no TPU is
-present.
+The kernels compile with Mosaic for the TPU unless the caller passes
+``interpret=True`` (the CPU tests do).
 """
 from __future__ import annotations
 
@@ -20,10 +18,6 @@ import jax.numpy as jnp
 from repro.core import fault, quant
 from repro.kernels import abft_matmul as _abft
 from repro.kernels import rollback_correct as _rc
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad2(x: jax.Array, bm: int, bn: int) -> jax.Array:
@@ -46,10 +40,8 @@ def drift_gemm(x: jax.Array, w: jax.Array, ckpt: Optional[jax.Array],
                threshold_bit: int = 10,
                bm: int = 128, bn: int = 128, bk: int = 128,
                union: bool = True,
-               interpret: Optional[bool] = None) -> DriftGemmOut:
+               interpret: bool = False) -> DriftGemmOut:
     """Kernel-backed DRIFT-protected GEMM: x (M,K) f32 @ w (K,N) f32."""
-    if interpret is None:
-        interpret = _default_interpret()
     m, k = x.shape
     n = w.shape[1]
 

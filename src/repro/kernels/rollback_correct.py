@@ -27,7 +27,8 @@ def _kernel(c_ref, ckpt_ref, rdiff_ref, cdiff_ref, thr_ref,
     cflag = (cd >= thr) | (cd <= -thr)
     mask = (rflag | cflag) if union else (rflag & cflag)
     out_ref[...] = jnp.where(mask, ckpt_ref[...], c_ref[...])
-    flag_ref[0, 0] = jnp.any(mask).astype(jnp.int32)
+    hit = jnp.max(mask.astype(jnp.int32), axis=1, keepdims=True)   # (bm, 1)
+    flag_ref[...] = jnp.max(hit, axis=0, keepdims=True)            # (1, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "union", "interpret"))
@@ -45,24 +46,30 @@ def rollback_correct(c: jax.Array, ckpt: jax.Array,
     assert m % bm == 0 and n % bn == 0
     mt, nt = m // bm, n // bn
     thr = jnp.asarray([threshold], jnp.int32)
+    # A leading tile axis keeps every block's last two dims (8, 128)-aligned
+    # or spanning the array (see abft_matmul): (bm, 1) of (nt, M, 1),
+    # (1, bn) of (mt, 1, N), (1, 1) of (mt, nt, 1, 1).
+    rows = row_diff.T[..., None]                       # (nt, M, 1)
+    cols = col_diff[:, None, :]                        # (mt, 1, N)
 
-    return pl.pallas_call(
+    out, flag = pl.pallas_call(
         functools.partial(_kernel, union=union),
         grid=(mt, nt),
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((1, bn), lambda i, j: (i, j)),
+            pl.BlockSpec((None, bm, 1), lambda i, j: (j, i, 0)),
+            pl.BlockSpec((None, 1, bn), lambda i, j: (i, 0, j)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=(
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((None, None, 1, 1), lambda i, j: (i, j, 0, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((m, n), c.dtype),
-            jax.ShapeDtypeStruct((mt, nt), jnp.int32),
+            jax.ShapeDtypeStruct((mt, nt, 1, 1), jnp.int32),
         ),
         interpret=interpret,
-    )(c, ckpt, row_diff, col_diff, thr)
+    )(c, ckpt, rows, cols, thr)
+    return out, flag[:, :, 0, 0]

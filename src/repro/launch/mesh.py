@@ -31,15 +31,14 @@ def make_serving_mesh(model_parallel: int = 1, devices=None):
     should be multiples of the data-axis size (otherwise the batch stays
     replicated; see ``distributed.sharding.batch_spec``). ``devices``
     restricts the mesh to a subset (tests carve a 4-device mesh out of 8
-    fake CPU devices); default is all local devices.
+    fake CPU devices); default is all local devices, ordered by
+    ``jax.make_mesh`` from their physical topology.
     """
-    import numpy as np
-
     devices = list(jax.devices()) if devices is None else list(devices)
     n = len(devices)
     if model_parallel < 1 or n % model_parallel:
         raise ValueError(
             f"model_parallel={model_parallel} does not divide {n} devices")
-    shape = (n // model_parallel, model_parallel)
-    from jax.sharding import Mesh
-    return Mesh(np.asarray(devices).reshape(shape), ("data", "model"))
+    auto = jax.sharding.AxisType.Auto     # GSPMD propagates the shardings
+    return jax.make_mesh((n // model_parallel, model_parallel),
+                         ("data", "model"), (auto, auto), devices=devices)

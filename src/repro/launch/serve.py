@@ -3,6 +3,14 @@
     PYTHONPATH=src python -m repro.launch.serve --arch dit-xl-512 --smoke \
         --batch 2 --steps 10 --mode drift --op undervolt
 
+``--smoke`` (the default) serves the arch's small test config;
+``--no-smoke`` serves its published widths, e.g. the 28 x 1152 DiT-XL/2
+at bucket 4 on one 16 GiB TPU v5e chip (``chip_smoke.py`` drives this):
+
+    PYTHONPATH=src python -m repro.launch.serve --arch dit-xl-512 \
+        --no-smoke --batch 4 --steps 20 --mode drift --op undervolt \
+        --requests 8
+
 Submits ``--requests`` generation requests (default: one bucket's worth)
 to a single engine instance and prints the structured per-request results:
 quality vs the engine's cached clean reference, and the perfmodel's
@@ -59,6 +67,7 @@ from typing import Optional, Sequence
 from repro import configs
 from repro.core import dvfs as dvfs_lib
 from repro.core.rollback import DEFAULT_INTERVAL
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import (DeadlineScheduler, DriftServeEngine,
                            EngineTelemetry, OffloadConfig, PreviewEvent,
                            ShardedDriftServeEngine, make_engine,
@@ -123,7 +132,10 @@ def build_parser() -> argparse.ArgumentParser:
                     help="model to serve; the engine picks the paradigm "
                          "from the ServableModel registry -- "
                          f"{arch_family_help()}")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the arch's small smoke config; --no-smoke "
+                         "serves its published widths")
     ap.add_argument("--batch", type=int, default=2,
                     help="micro-batch bucket size")
     ap.add_argument("--requests", type=int, default=0,
@@ -234,6 +246,7 @@ def build_engine(args) -> DriftServeEngine:
 def main(argv: Optional[Sequence[str]] = None,
          engine: Optional[DriftServeEngine] = None) -> list:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
 
     eng = engine if engine is not None else build_engine(args)
     if isinstance(eng, ShardedDriftServeEngine):

@@ -112,6 +112,22 @@ def _modulate(x: jax.Array, shift: jax.Array, scale: jax.Array) -> jax.Array:
     return x * (1.0 + scale[:, None, :]) + shift[:, None, :]
 
 
+def _modulation(c: jax.Array, w: jax.Array, b: jax.Array,
+                dtype) -> jax.Array:
+    """adaLN modulation silu(c) @ w + b, for the whole batch on every device
+    of a mesh, so that a data-parallel batch matches the single-device one
+    bit for bit. Sharded over ``data``, the dot would keep one row per
+    device when the bucket equals the data size, and XLA rewrites such a
+    dot as a bf16 multiply-reduce whose sums differ from the batched dot's;
+    the weight is gathered over ``data`` because GSPMD would otherwise sum
+    per-device partial products of its FSDP-sharded K dim. Both operands
+    are small (B x d and d x 6d), so replicating them costs little."""
+    w = constrain(w.astype(dtype), "w2d_model")
+    c = constrain(jax.nn.silu(c.astype(jnp.float32)).astype(dtype),
+                  "replicated")
+    return constrain(c @ w + b.astype(dtype), "replicated")
+
+
 def _proj(ctx, x, w, name, rclass):
     if ctx is None:
         return x @ w.astype(x.dtype)
@@ -128,8 +144,7 @@ def dit_block(cfg: ModelConfig, p: Params, x: jax.Array, c: jax.Array,
               rclass=dvfs.CLASS_BODY) -> jax.Array:
     b, t, d = x.shape
     h, hd = cfg.n_heads, cfg.hd
-    mod = (jax.nn.silu(c.astype(jnp.float32)).astype(x.dtype)
-           @ p["adaln_w"].astype(x.dtype) + p["adaln_b"].astype(x.dtype))
+    mod = _modulation(c, p["adaln_w"], p["adaln_b"], x.dtype)
     s1, sc1, g1, s2, sc2, g2 = jnp.split(mod, 6, axis=-1)
 
     xn = _modulate(layernorm(x, None, None), s1, sc1)
@@ -246,9 +261,8 @@ def forward(cfg: ModelConfig, params: Params, latents: jax.Array,
                                unroll=not cfg.scan_layers)
     new_block_store, corrected, detected = ys
 
-    mod = (jax.nn.silu(c.astype(jnp.float32)).astype(x.dtype)
-           @ params["final_adaln_w"].astype(x.dtype)
-           + params["final_adaln_b"].astype(x.dtype))
+    mod = _modulation(c, params["final_adaln_w"], params["final_adaln_b"],
+                      x.dtype)
     shift, scale = jnp.split(mod, 2, axis=-1)
     x = _modulate(layernorm(x, None, None), shift, scale)
     x = _proj(ectx, x, params["final_w"], "final", dvfs.CLASS_EMBED)

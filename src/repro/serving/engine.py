@@ -81,6 +81,7 @@ Architecture walk-through: ``docs/serving.md``.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import zlib
 from typing import Callable, Dict, List, Optional, Tuple
@@ -238,6 +239,12 @@ class DriftServeEngine:
             sv = self._servables[paradigm] = servable_lib.build_servable(
                 paradigm, self)
         return sv
+
+    def placement(self):
+        """Context in which this engine traces and runs its compiled
+        functions: nothing here; the sharded engine's mesh and activation
+        sharding policy."""
+        return contextlib.nullcontext()
 
     def place_inputs(self, tree):
         """Device placement hook for servable-built batch inputs: identity
@@ -500,6 +507,13 @@ class DriftServeEngine:
         return {}
 
     # ------------------------------------------------------------ helpers
+    def load_params(self, arch: str, smoke: bool, params) -> None:
+        """Serve ``params`` for (arch, smoke) in place of the seeded random
+        initialisation: a trained checkpoint, or any weights with the
+        config's tree structure. The sharded engine places them on its
+        mesh."""
+        self._params[(arch, smoke)] = params
+
     def _params_for(self, arch: str, smoke: bool):
         k = (arch, smoke)
         if k not in self._params:
@@ -507,8 +521,8 @@ class DriftServeEngine:
             # crc32, not hash(): Python randomizes str hashes per process,
             # and param init must be reproducible across runs.
             tag = zlib.crc32(f"{arch}:{smoke}".encode()) & 0x7FFFFFFF
-            self._params[k] = steps_lib.init_model_params(
-                cfg, jax.random.fold_in(self._base_key, tag))
+            self.load_params(arch, smoke, steps_lib.init_model_params(
+                cfg, jax.random.fold_in(self._base_key, tag)))
         return self._params[k]
 
     def _energy_model_for(self):

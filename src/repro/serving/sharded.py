@@ -54,6 +54,7 @@ docs/serving.md.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, Optional
 
 import jax
@@ -109,13 +110,9 @@ class ShardedDriftServeEngine(DriftServeEngine):
                                         on_window=self._on_stream_window,
                                         on_carry=self._offload_on_carry)
 
-    def _params_for(self, arch: str, smoke: bool):
-        k = (arch, smoke)
-        if k not in self._params:
-            params = super()._params_for(arch, smoke)
-            self._params[k] = jax.device_put(
-                params, shd.shardings_for(params, self.mesh))
-        return self._params[k]
+    def load_params(self, arch: str, smoke: bool, params) -> None:
+        super().load_params(arch, smoke, jax.device_put(
+            params, shd.shardings_for(params, self.mesh)))
 
     def place_inputs(self, tree):
         # Batch-shaped staged inputs (whatever the paradigm's ServableModel
@@ -125,7 +122,8 @@ class ShardedDriftServeEngine(DriftServeEngine):
         return jax.tree.map(put, tree)
 
     # ------------------------------------------------------------ one batch
-    def _run_batch(self, mb):
+    @contextlib.contextmanager
+    def placement(self):
         # the MeshPolicy anchors activation shardings inside the model (see
         # distributed/constraints.py) and the ambient mesh lets bare
         # PartitionSpecs inside the jitted sampler resolve; restore both so
@@ -134,22 +132,21 @@ class ShardedDriftServeEngine(DriftServeEngine):
         constraints.set_policy(constraints.MeshPolicy(self.mesh))
         try:
             with self.mesh:
-                return super()._run_batch(mb)
+                yield
         finally:
             constraints.set_policy(prev)
 
+    def _run_batch(self, mb):
+        with self.placement():
+            return super()._run_batch(mb)
+
     def _run_batch_stream(self, mb, preview_interval):
-        # Same mesh/policy bracketing as _run_batch, but held open across
-        # the whole generator: every window (and the consumer code between
-        # yields) runs inside it. The engine is single-threaded, so don't
-        # interleave another engine's batches while a stream is mid-batch.
-        prev = constraints.get_policy()
-        constraints.set_policy(constraints.MeshPolicy(self.mesh))
-        try:
-            with self.mesh:
-                yield from super()._run_batch_stream(mb, preview_interval)
-        finally:
-            constraints.set_policy(prev)
+        # held open across the whole generator: every window (and the
+        # consumer code between yields) runs inside it. The engine is
+        # single-threaded, so don't interleave another engine's batches
+        # while a stream is mid-batch.
+        with self.placement():
+            yield from super()._run_batch_stream(mb, preview_interval)
 
 
 def make_engine(mesh: Optional[Mesh] = None, model_parallel: int = 1,

@@ -4,10 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                         # deterministic local fallback
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (abft, baselines, dvfs, exec_ctx, fault, metrics,
                         policies, quant, repack, rollback)

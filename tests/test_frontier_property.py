@@ -13,11 +13,7 @@ both implementations keep), which is what makes the scheduler's
 "search the pruned set" == "search the full enumeration" argument hold.
 """
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                                   # pragma: no cover
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.serving import frontier
 from repro.serving.offload import planner as offload_planner

@@ -13,11 +13,7 @@ every element's byte address.
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                                   # pragma: no cover
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import repack
 from repro.perfmodel import dram

@@ -401,12 +401,39 @@ def test_streaming_through_scheduler_cli_shape():
 
 
 # ------------------------------------------------------------- help sync
-def test_serve_cli_help_enumerates_ladder_and_flags():
-    """Tier-1 twin of tools/check_help_sync.py for the importable CLI."""
+def _example_parser():
+    import importlib.util
+    import pathlib
+    path = (pathlib.Path(__file__).resolve().parents[1] / "examples"
+            / "drift_serve.py")
+    spec = importlib.util.spec_from_file_location("drift_serve_example",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.build_parser()
+
+
+def _serve_parser():
     from repro.launch import serve as serve_cli
-    text = serve_cli.build_parser().format_help()
+    return serve_cli.build_parser()
+
+
+@pytest.mark.parametrize("build_parser", [_serve_parser, _example_parser],
+                         ids=["launch.serve", "examples.drift_serve"])
+def test_serve_cli_help_enumerates_ladder_and_flags(build_parser):
+    """Tier-1 twin of tools/check_help_sync.py for both serving CLIs."""
+    text = build_parser().format_help()
     for p in dvfs.OP_LADDER:
         assert p.name in text, f"--help lost ladder point {p.name}"
     for flag in ("--priority", "--deadline", "--step-budget", "--stream",
-                 "--op", "--metrics-port", "--no-telemetry"):
+                 "--op", "--metrics-port", "--no-telemetry", "--smoke",
+                 "--no-smoke"):
         assert flag in text, f"--help lost {flag}"
+
+
+def test_no_smoke_selects_published_widths():
+    from repro.launch import serve as serve_cli
+    parse = serve_cli.build_parser().parse_args
+    assert parse([]).smoke is True
+    assert parse(["--no-smoke"]).smoke is False
+    assert _example_parser().parse_args(["--no-smoke"]).smoke is False

@@ -166,6 +166,27 @@ def test_clean_mode_requests_do_not_feed_monitor():
 
 
 # --------------------------------------------- single-device degradation
+def test_load_params_serves_the_given_weights():
+    seen = []
+    inner = fake_factory()
+
+    def factory(key, model_cfg, scfg, on_trace):
+        run = inner(key, model_cfg, scfg, on_trace)
+
+        def recording(params, *args):
+            seen.append(params)
+            return run(params, *args)
+        return recording
+
+    eng = DriftServeEngine(arch="dit-xl-512", smoke=True, bucket=1,
+                           sampler_factory=factory)
+    weights = {"checkpoint": jnp.ones(3)}
+    eng.load_params("dit-xl-512", True, weights)
+    eng.submit(steps=3, mode="clean", seed=0)
+    eng.run()
+    assert seen and all(p is weights for p in seen)
+
+
 def test_make_engine_falls_back_on_one_device(monkeypatch):
     """With nothing to shard over, the factory must return the plain
     single-device engine (same class PR 1 shipped), not a mesh wrapper."""

@@ -7,7 +7,8 @@ Checks, for both ``python -m repro.launch.serve`` and
 * every operating point in ``core.dvfs.OP_LADDER`` is named in the help
   text (the CLIs derive it from the ladder programmatically -- this guard
   catches someone replacing that with a stale literal);
-* every scheduling/streaming/offload flag the docs advertise is present;
+* every scheduling/streaming/offload flag the docs advertise is present,
+  and ``--smoke/--no-smoke`` (the switch to published widths);
 * the ``--rollback-interval`` help renders its default from
   ``core.rollback.DEFAULT_INTERVAL`` (the single source of truth -- the
   old CLIs duplicated the literal 10 in help strings, which is exactly
@@ -38,7 +39,8 @@ REQUIRED_FLAGS = ("--op", "--priority", "--deadline", "--step-budget",
                   "--stream", "--batch", "--steps", "--arch",
                   "--metrics-port", "--no-telemetry",
                   "--rollback-interval", "--offload",
-                  "--energy-budget", "--quality-floor", "--trace-dir")
+                  "--energy-budget", "--quality-floor", "--trace-dir",
+                  "--smoke", "--no-smoke")
 # --arch help must be registry-derived: every registered config by name,
 # plus the paradigm labels the registry groups them under.
 PARADIGM_WORDS = ("diffusion", "autoregressive", "unsupported")
