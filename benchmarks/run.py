@@ -28,7 +28,6 @@ MODULES = [
     "serving_telemetry",
     "ar_serving",
     "offload_overlap",
-    "trace_forensics",
     "energy_slo",
 ]
 

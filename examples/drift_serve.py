@@ -320,7 +320,8 @@ def _drive(args, engine, server, ops, priorities, deadlines):
         from repro.serving.trace import write_chrome_trace
         os.makedirs(args.trace_dir, exist_ok=True)
         path = os.path.join(args.trace_dir, "flight.json")
-        write_chrome_trace(path, engine.tracer.spans())
+        write_chrome_trace(path, engine.tracer.spans(),
+                           engine.tracer.epoch_unix_ns)
         print(f"trace: {len(engine.tracer)} spans -> {path}")
 
 

@@ -10,6 +10,11 @@ Every model in ``repro.models`` routes its projections through
   * detection + correction strategy (DRIFT rollback-ABFT or a baseline),
   * checkpoint-store reads/writes (rollback source, refreshed every n steps).
 
+Each phase of ``matmul`` runs under a ``jax.named_scope`` (``drift.quantize``,
+``drift.gemm``, ``drift.inject``, ``drift.checksum``, ``drift.correct``,
+``drift.ckpt``), which a device trace keeps in the ops' metadata
+(docs/tracing.md). Scopes change metadata only, never the computation.
+
 The context is created fresh inside each traced step; its mutable Python
 dicts are trace-time containers (a la Flax mutable collections): the caller
 extracts ``ctx.state_out`` / ``ctx.stats`` and threads them through the
@@ -97,82 +102,103 @@ class ExecContext:
         n = w.shape[-1]
         x2 = x.reshape(-1, k)
 
-        xq = quant.quantize(x2, axis=None)
-        wq = quant.quantize(w, axis=1)
-        # Under pjit, gather FSDP-sharded int8 weights over the data axis
-        # before the GEMM: int8 gathers cost half the clean path's bf16
-        # gathers, and the INT32 accumulator stays shard-local (otherwise
-        # GSPMD all-reduces (M, N) int32 partial sums per GEMM -- measured
-        # 3.4x collective blowup on the 512-chip drift dry-run).
-        from repro.distributed.constraints import constrain
-        wq_q = constrain(wq.q, "w2d_model")
-        acc = quant.int32_matmul(xq.q, wq_q)
+        with jax.named_scope("drift.quantize"):
+            xq = quant.quantize(x2, axis=None)
+            wq = quant.quantize(w, axis=1)
+            # Under pjit, gather FSDP-sharded int8 weights over the data
+            # axis before the GEMM: int8 gathers cost half the clean path's
+            # bf16 gathers, and the INT32 accumulator stays shard-local
+            # (otherwise GSPMD all-reduces (M, N) int32 partial sums per
+            # GEMM -- measured 3.4x collective blowup on the 512-chip drift
+            # dry-run).
+            from repro.distributed.constraints import constrain
+            wq_q = constrain(wq.q, "w2d_model")
         w_scale = wq.scale.reshape(1, -1)
+        with jax.named_scope("drift.gemm"):
+            acc = quant.int32_matmul(xq.q, wq_q)
 
         if self.cfg.mode == "clean":
-            y = quant.dequantize_matmul(acc, xq.scale, w_scale)
-            return y.reshape(*lead, n).astype(x.dtype)
+            with jax.named_scope("drift.gemm"):
+                y = quant.dequantize_matmul(acc, xq.scale, w_scale)
+                return y.reshape(*lead, n).astype(x.dtype)
 
         ber = self.ber_by_class[jnp.asarray(rclass, jnp.int32)]
         site = _site_id(name)
-        fkey = fault.site_key(self.key, self.step, site, 0)
-        acc_faulty = fault.inject_int32(acc, fkey, ber,
-                                        double_flip=self.cfg.double_flip,
-                                        force_bit=self.cfg.force_bit)
+        with jax.named_scope("drift.inject"):
+            fkey = fault.site_key(self.key, self.step, site, 0)
+            acc_faulty = fault.inject_int32(acc, fkey, ber,
+                                            double_flip=self.cfg.double_flip,
+                                            force_bit=self.cfg.force_bit)
 
         if self.cfg.mode == "faulty":
-            y = quant.dequantize_matmul(acc_faulty, xq.scale, w_scale)
-            return y.reshape(*lead, n).astype(x.dtype)
+            with jax.named_scope("drift.gemm"):
+                y = quant.dequantize_matmul(acc_faulty, xq.scale, w_scale)
+                return y.reshape(*lead, n).astype(x.dtype)
 
-        report = abft_lib.detect_int(acc_faulty, xq.q, wq_q, self.cfg.abft)
-        y_faulty = quant.dequantize_matmul(acc_faulty, xq.scale, w_scale)
-        self._bump("detected_row_errors", report.n_row_err)
-        self._bump("gemm_words", jnp.int32(acc.size))
+        with jax.named_scope("drift.checksum"):
+            report = abft_lib.detect_int(acc_faulty, xq.q, wq_q,
+                                         self.cfg.abft)
+            self._bump("detected_row_errors", report.n_row_err)
+            self._bump("gemm_words", jnp.int32(acc.size))
+        with jax.named_scope("drift.gemm"):
+            y_faulty = quant.dequantize_matmul(acc_faulty, xq.scale, w_scale)
 
         if self.cfg.mode == "drift":
             # Tile-granular recovery (Sec 5.4): the recovery scheduler works
             # tile-by-tile, so the row x col cross-combine happens *within*
             # each systolic tile -- far sparser masks than a full-matrix
             # cross at high BER, and exactly what the Pallas kernel emits.
-            rd, cd = abft_lib.tile_checksum_diff(acc_faulty, xq.q, wq_q,
-                                                 self.cfg.abft)
-            mask, tile_flag = abft_lib.tile_error_mask(rd, cd, self.cfg.abft,
-                                                       acc.shape)
-            ckpt = self.state_in.get(name)
-            y = rollback.correct(y_faulty, ckpt, mask, self.have_ckpt)
-            n_corr = jnp.sum(mask.astype(jnp.int32))
-            # DRAM cost: one repacked-tile read per flagged tile.
-            tile_bytes = self.cfg.abft.tile_m * self.cfg.abft.tile_n * 4
-            cost = baselines.RecoveryCost(
-                jnp.float32(0.0),
-                jnp.sum(tile_flag.astype(jnp.float32)) * tile_bytes,
-                n_corr)
-            self._write_ckpt(name, y)
+            with jax.named_scope("drift.checksum"):
+                rd, cd = abft_lib.tile_checksum_diff(acc_faulty, xq.q, wq_q,
+                                                     self.cfg.abft)
+                mask, tile_flag = abft_lib.tile_error_mask(
+                    rd, cd, self.cfg.abft, acc.shape)
+            with jax.named_scope("drift.correct"):
+                ckpt = self.state_in.get(name)
+                y = rollback.correct(y_faulty, ckpt, mask, self.have_ckpt)
+                n_corr = jnp.sum(mask.astype(jnp.int32))
+                # DRAM cost: one repacked-tile read per flagged tile.
+                tile_bytes = self.cfg.abft.tile_m * self.cfg.abft.tile_n * 4
+                cost = baselines.RecoveryCost(
+                    jnp.float32(0.0),
+                    jnp.sum(tile_flag.astype(jnp.float32)) * tile_bytes,
+                    n_corr)
+            with jax.named_scope("drift.ckpt"):
+                self._write_ckpt(name, y)
         elif self.cfg.mode == "thundervolt":
-            y, cost = baselines.thundervolt(y_faulty, report)
+            with jax.named_scope("drift.correct"):
+                y, cost = baselines.thundervolt(y_faulty, report)
         elif self.cfg.mode == "approx_abft":
-            y, cost = baselines.approx_abft(y_faulty, report)
+            with jax.named_scope("drift.correct"):
+                y, cost = baselines.approx_abft(y_faulty, report)
         elif self.cfg.mode == "dmr":
-            y_clean = quant.dequantize_matmul(acc, xq.scale, w_scale)
-            y, cost = baselines.dmr(y_clean, report.n_row_err,
-                                    gemm_flops=2.0 * x2.shape[0] * k * n)
+            with jax.named_scope("drift.gemm"):
+                y_clean = quant.dequantize_matmul(acc, xq.scale, w_scale)
+            with jax.named_scope("drift.correct"):
+                y, cost = baselines.dmr(y_clean, report.n_row_err,
+                                        gemm_flops=2.0 * x2.shape[0] * k * n)
         elif self.cfg.mode == "stat_abft":
-            y_clean = quant.dequantize_matmul(acc, xq.scale, w_scale)
-            rd, cd = abft_lib.tile_checksum_diff(acc_faulty, xq.q, wq_q,
-                                                 self.cfg.abft)
-            _, tile_flag = abft_lib.tile_error_mask(rd, cd, self.cfg.abft,
-                                                    acc.shape)
-            y, cost = baselines.stat_abft(
-                y_clean, y_faulty, tile_flag,
-                tile_elems=self.cfg.abft.tile_m * self.cfg.abft.tile_n,
-                k_dim=k)
+            with jax.named_scope("drift.gemm"):
+                y_clean = quant.dequantize_matmul(acc, xq.scale, w_scale)
+            with jax.named_scope("drift.checksum"):
+                rd, cd = abft_lib.tile_checksum_diff(acc_faulty, xq.q, wq_q,
+                                                     self.cfg.abft)
+                _, tile_flag = abft_lib.tile_error_mask(rd, cd, self.cfg.abft,
+                                                        acc.shape)
+            with jax.named_scope("drift.correct"):
+                y, cost = baselines.stat_abft(
+                    y_clean, y_faulty, tile_flag,
+                    tile_elems=self.cfg.abft.tile_m * self.cfg.abft.tile_n,
+                    k_dim=k)
         else:  # pragma: no cover
             raise ValueError(self.cfg.mode)
 
-        self._bump("corrected_elems", cost.corrected_elems)
-        self._bump("extra_compute_flops", cost.extra_compute_flops)
-        self._bump("extra_dram_bytes", cost.extra_dram_bytes)
-        return y.reshape(*lead, n).astype(x.dtype)
+        with jax.named_scope("drift.correct"):
+            self._bump("corrected_elems", cost.corrected_elems)
+            self._bump("extra_compute_flops", cost.extra_compute_flops)
+            self._bump("extra_dram_bytes", cost.extra_dram_bytes)
+        with jax.named_scope("drift.gemm"):
+            return y.reshape(*lead, n).astype(x.dtype)
 
     # ------------------------------------------------------------------
     def bmm(self, a: jax.Array, b: jax.Array, *, name: str,

@@ -185,8 +185,9 @@ def _init_carry(model_cfg: ModelConfig, params, latents0, cond, text,
                 cfg: SamplerConfig, monitor0, ts):
     b = latents0.shape[0]
     t0 = jnp.full((b,), float(ts[0]), jnp.float32)
-    stores0 = init_stores(model_cfg, params, latents0, t0, cond, text,
-                          cfg.drift)
+    with jax.named_scope("drift.ckpt"):
+        stores0 = init_stores(model_cfg, params, latents0, t0, cond, text,
+                              cfg.drift)
     taylor0 = ts_lib.init_state(latents0.shape)
     mon0 = monitor0 if monitor0 is not None else dvfs_lib.ber_monitor_init()
     # The corrected-element total is carried in float32: one step's count
@@ -207,10 +208,11 @@ def _make_step_fn(model_cfg: ModelConfig, cfg: SamplerConfig, sched,
         latents, stores, taylor, mon, corrected, nevals = carry
         i, t_now, t_nxt = inp
         b = latents.shape[0]
-        tvec = jnp.full((b,), t_now, jnp.float32)
-        ber_by_class = ber_table[jnp.minimum(i, ber_table.shape[0] - 1)]
-        drift_inputs = (cfg.drift, jax.random.fold_in(key, i), i,
-                        ber_by_class, stores, i > 0)
+        with jax.named_scope("sampler.step"):
+            tvec = jnp.full((b,), t_now, jnp.float32)
+            ber_by_class = ber_table[jnp.minimum(i, ber_table.shape[0] - 1)]
+            drift_inputs = (cfg.drift, jax.random.fold_in(key, i), i,
+                            ber_by_class, stores, i > 0)
 
         def do_compute(_):
             eps, new_stores, corr, detected, det_blocks = _model_eval(
@@ -245,15 +247,17 @@ def _make_step_fn(model_cfg: ModelConfig, cfg: SamplerConfig, sched,
             qeps = quant_lib.fake_quant(eps, cfg.precision.body_bits)
             eps = jnp.where(i >= cfg.precision.protect_steps, qeps, eps)
 
-        n_words = max(int(np.prod(latents.shape)), 1)
-        mon2 = dvfs_lib.ber_monitor_update(
-            mon, detected, n_words, cfg.drift.abft.threshold_bit,
-            cfg.monitor_target_ber)
-        new_latents = sched.ddim_step(latents, eps, t_now, t_nxt)
+        with jax.named_scope("sampler.step"):
+            n_words = max(int(np.prod(latents.shape)), 1)
+            mon2 = dvfs_lib.ber_monitor_update(
+                mon, detected, n_words, cfg.drift.abft.threshold_bit,
+                cfg.monitor_target_ber)
+            new_latents = sched.ddim_step(latents, eps, t_now, t_nxt)
+            corrected2 = corrected + corr.astype(jnp.float32)
+            nevals2 = nevals + ran
         # The per-site detection vector rides the scan's ys slot: stacked
         # over steps it becomes the (steps, rows) resilience heatmap.
-        return (new_latents, stores2, taylor2, mon2,
-                corrected + corr.astype(jnp.float32), nevals + ran), \
+        return (new_latents, stores2, taylor2, mon2, corrected2, nevals2), \
             det_blocks
 
     return step_fn
@@ -431,6 +435,8 @@ def make_sampler(model_cfg: ModelConfig, cfg: SamplerConfig,
                 heat = jax.lax.with_sharding_constraint(heat, replicated)
             return carry, heat
 
+        # the device trace names the executable jit_sample_<mode>_window
+        _window.__name__ = f"sample_{cfg.drift.mode}_window"
         window_jit = jax.jit(_window)
 
         def _run_stream(params, key, latents0, cond, text, monitor0):
@@ -456,4 +462,8 @@ def make_sampler(model_cfg: ModelConfig, cfg: SamplerConfig,
             total_corrected=pin_rep(out.total_corrected),
             n_model_evals=pin_rep(out.n_model_evals),
             heatmap=pin_rep(out.heatmap))
+    # the device trace names the executable jit_sample_<mode>: in a drift
+    # batch, jit_sample_drift is the served sampler, jit_sample_clean the
+    # clean twin
+    _run.__name__ = f"sample_{cfg.drift.mode}"
     return jax.jit(_run)

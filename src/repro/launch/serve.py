@@ -388,7 +388,8 @@ def _drive(args, eng, server, n_requests: int, bucket: int) -> list:
         from repro.serving.trace import write_chrome_trace
         os.makedirs(args.trace_dir, exist_ok=True)
         path = os.path.join(args.trace_dir, "flight.json")
-        write_chrome_trace(path, eng.tracer.spans())
+        write_chrome_trace(path, eng.tracer.spans(),
+                           eng.tracer.epoch_unix_ns)
         print(f"  trace: {len(eng.tracer)} spans -> {path} "
               f"(ui.perfetto.dev)")
     return results

@@ -13,6 +13,7 @@ a flat dict for the embedding GEMMs, carried by the sampler's scan.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Dict, Optional, Tuple
 
@@ -132,8 +133,9 @@ def _proj(ctx, x, w, name, rclass):
     if ctx is None:
         return x @ w.astype(x.dtype)
     lead = x.shape[:-1]
-    y = ctx.matmul(x.reshape(-1, x.shape[-1]), w.astype(x.dtype),
-                   name=name, rclass=rclass)
+    with jax.named_scope("drift.quantize"):
+        w = w.astype(x.dtype)
+    y = ctx.matmul(x.reshape(-1, x.shape[-1]), w, name=name, rclass=rclass)
     return y.reshape(*lead, -1)
 
 
@@ -144,33 +146,46 @@ def dit_block(cfg: ModelConfig, p: Params, x: jax.Array, c: jax.Array,
               rclass=dvfs.CLASS_BODY) -> jax.Array:
     b, t, d = x.shape
     h, hd = cfg.n_heads, cfg.hd
-    mod = _modulation(c, p["adaln_w"], p["adaln_b"], x.dtype)
-    s1, sc1, g1, s2, sc2, g2 = jnp.split(mod, 6, axis=-1)
-
-    xn = _modulate(layernorm(x, None, None), s1, sc1)
-    q = _proj(ctx, xn, p["attn"]["wq"], "attn.q", rclass).reshape(b, t, h, hd)
-    k = _proj(ctx, xn, p["attn"]["wk"], "attn.k", rclass).reshape(b, t, h, hd)
-    v = _proj(ctx, xn, p["attn"]["wv"], "attn.v", rclass).reshape(b, t, h, hd)
-    o = attention.attention_any(q, k, v, causal=False)
-    o = _proj(ctx, o.reshape(b, t, h * hd), p["attn"]["wo"], "attn.o", rclass)
-    x = x + g1[:, None, :] * o
+    with jax.named_scope("dit.modulation"):
+        mod = _modulation(c, p["adaln_w"], p["adaln_b"], x.dtype)
+        s1, sc1, g1, s2, sc2, g2 = jnp.split(mod, 6, axis=-1)
+        xn = _modulate(layernorm(x, None, None), s1, sc1)
+    q = _proj(ctx, xn, p["attn"]["wq"], "attn.q", rclass)
+    k = _proj(ctx, xn, p["attn"]["wk"], "attn.k", rclass)
+    v = _proj(ctx, xn, p["attn"]["wv"], "attn.v", rclass)
+    with jax.named_scope("dit.attention"):
+        o = attention.attention_any(q.reshape(b, t, h, hd),
+                                    k.reshape(b, t, h, hd),
+                                    v.reshape(b, t, h, hd), causal=False)
+        o = o.reshape(b, t, h * hd)
+    o = _proj(ctx, o, p["attn"]["wo"], "attn.o", rclass)
+    with jax.named_scope("dit.modulation"):
+        x = x + g1[:, None, :] * o
 
     if text is not None and "xattn" in p:
-        xn = layernorm(x, None, None)
-        q = _proj(ctx, xn, p["xattn"]["wq"], "xattn.q", rclass
-                  ).reshape(b, t, h, hd)
-        k = _proj(ctx, text, p["xattn"]["wk"], "xattn.k", rclass
-                  ).reshape(b, -1, h, hd)
-        v = _proj(ctx, text, p["xattn"]["wv"], "xattn.v", rclass
-                  ).reshape(b, -1, h, hd)
-        o = attention.full_attention(q, k, v, causal=False)
-        x = x + _proj(ctx, o.reshape(b, t, h * hd), p["xattn"]["wo"],
-                      "xattn.o", rclass)
+        with jax.named_scope("dit.modulation"):
+            xn = layernorm(x, None, None)
+        q = _proj(ctx, xn, p["xattn"]["wq"], "xattn.q", rclass)
+        k = _proj(ctx, text, p["xattn"]["wk"], "xattn.k", rclass)
+        v = _proj(ctx, text, p["xattn"]["wv"], "xattn.v", rclass)
+        with jax.named_scope("dit.attention"):
+            o = attention.full_attention(q.reshape(b, t, h, hd),
+                                         k.reshape(b, -1, h, hd),
+                                         v.reshape(b, -1, h, hd),
+                                         causal=False)
+            o = o.reshape(b, t, h * hd)
+        o = _proj(ctx, o, p["xattn"]["wo"], "xattn.o", rclass)
+        with jax.named_scope("dit.modulation"):
+            x = x + o
 
-    xn = _modulate(layernorm(x, None, None), s2, sc2)
+    with jax.named_scope("dit.modulation"):
+        xn = _modulate(layernorm(x, None, None), s2, sc2)
     hdn = _proj(ctx, xn, p["mlp_w1"], "mlp.w1", rclass)
-    hdn = jax.nn.gelu(hdn.astype(jnp.float32)).astype(x.dtype)
-    x = x + g2[:, None, :] * _proj(ctx, hdn, p["mlp_w2"], "mlp.w2", rclass)
+    with jax.named_scope("dit.modulation"):
+        hdn = jax.nn.gelu(hdn.astype(jnp.float32)).astype(x.dtype)
+    hdn = _proj(ctx, hdn, p["mlp_w2"], "mlp.w2", rclass)
+    with jax.named_scope("dit.modulation"):
+        x = x + g2[:, None, :] * hdn
     return x
 
 
@@ -213,39 +228,48 @@ def forward(cfg: ModelConfig, params: Params, latents: jax.Array,
                            state_in=drift.embed_store,
                            have_ckpt=drift.have_ckpt)
 
-    x = patchify(cfg, latents.astype(cfg.dtype))
+    with jax.named_scope("dit.embed"):
+        x = patchify(cfg, latents.astype(cfg.dtype))
     x = _proj(ectx, x, params["patch_w"], "patch", dvfs.CLASS_EMBED)
-    x = x + params["patch_b"].astype(x.dtype) + params["pos_embed"].astype(x.dtype)
-    x = constrain(x, "act")
-
-    temb = timestep_embedding(t).astype(cfg.dtype)
+    with jax.named_scope("dit.embed"):
+        x = (x + params["patch_b"].astype(x.dtype)
+             + params["pos_embed"].astype(x.dtype))
+        x = constrain(x, "act")
+        temb = timestep_embedding(t).astype(cfg.dtype)
     temb = _proj(ectx, temb, params["t_w1"], "t.w1", dvfs.CLASS_EMBED)
-    temb = jax.nn.silu(temb + params["t_b1"].astype(temb.dtype))
+    with jax.named_scope("dit.embed"):
+        temb = jax.nn.silu(temb + params["t_b1"].astype(temb.dtype))
     temb = _proj(ectx, temb, params["t_w2"], "t.w2", dvfs.CLASS_EMBED)
-    temb = temb + params["t_b2"].astype(temb.dtype)
+    with jax.named_scope("dit.embed"):
+        temb = temb + params["t_b2"].astype(temb.dtype)
 
     text_proj = None
     if cfg.cond_tokens:
         text_proj = _proj(ectx, text.astype(cfg.dtype), params["text_proj"],
                           "text", dvfs.CLASS_EMBED)
-        c = temb + text_proj.mean(axis=1)
+        with jax.named_scope("dit.embed"):
+            c = temb + text_proj.mean(axis=1)
     else:
-        c = temb + params["class_embed"].astype(cfg.dtype)[cond]
+        with jax.named_scope("dit.embed"):
+            c = temb + params["class_embed"].astype(cfg.dtype)[cond]
 
     def body(xc, p_i, extra):
         layer_idx, store_i = extra
         bctx = None
         if drift is not None:
-            rcl = jnp.where(layer_idx < 1, dvfs.CLASS_FIRST_BLOCK,
-                            dvfs.CLASS_BODY)
-            b_ber = drift.ber_by_class
-            if drift.layer_gate is not None:
-                b_ber = b_ber * jnp.asarray(drift.layer_gate)[layer_idx]
-            bctx = ExecContext(drift.cfg,
-                               key=jax.random.fold_in(drift.key, layer_idx),
-                               step=drift.step,
-                               ber_by_class=b_ber,
-                               state_in=store_i, have_ckpt=drift.have_ckpt)
+            with jax.named_scope("drift.inject"):    # this layer's BER, key
+                rcl = jnp.where(layer_idx < 1, dvfs.CLASS_FIRST_BLOCK,
+                                dvfs.CLASS_BODY)
+                b_ber = drift.ber_by_class
+                if drift.layer_gate is not None:
+                    b_ber = b_ber * jnp.asarray(drift.layer_gate)[layer_idx]
+                bctx = ExecContext(drift.cfg,
+                                   key=jax.random.fold_in(drift.key,
+                                                          layer_idx),
+                                   step=drift.step,
+                                   ber_by_class=b_ber,
+                                   state_in=store_i,
+                                   have_ckpt=drift.have_ckpt)
             y = dit_block(cfg, p_i, xc, c, text_proj, ctx=bctx, rclass=rcl)
             return constrain(y, "act"), (bctx.state_out,
                                          bctx.stats["corrected_elems"],
@@ -256,18 +280,26 @@ def forward(cfg: ModelConfig, params: Params, latents: jax.Array,
     n_layers = cfg.n_layers
     xs = (jnp.arange(n_layers, dtype=jnp.int32),
           drift.block_store if drift is not None else None)
-    x, ys = common.scan_layers(body, x, params["blocks"], xs_extra=xs,
-                               remat=cfg.remat and drift is None,
-                               unroll=not cfg.scan_layers)
+    # Under drift the layer scan slices each layer's checkpoint store and
+    # stacks its store write, and XLA fuses the write's select into that
+    # stacking: the scan's own ops are the store's, so they are named so
+    # (the blocks' ops keep their inner scopes).
+    with (jax.named_scope("drift.ckpt") if drift is not None
+          else contextlib.nullcontext()):
+        x, ys = common.scan_layers(body, x, params["blocks"], xs_extra=xs,
+                                   remat=cfg.remat and drift is None,
+                                   unroll=not cfg.scan_layers)
     new_block_store, corrected, detected = ys
 
-    mod = _modulation(c, params["final_adaln_w"], params["final_adaln_b"],
-                      x.dtype)
-    shift, scale = jnp.split(mod, 2, axis=-1)
-    x = _modulate(layernorm(x, None, None), shift, scale)
+    with jax.named_scope("dit.modulation"):
+        mod = _modulation(c, params["final_adaln_w"],
+                          params["final_adaln_b"], x.dtype)
+        shift, scale = jnp.split(mod, 2, axis=-1)
+        x = _modulate(layernorm(x, None, None), shift, scale)
     x = _proj(ectx, x, params["final_w"], "final", dvfs.CLASS_EMBED)
-    x = x + params["final_b"].astype(x.dtype)
-    eps = unpatchify(cfg, x, hh, ww).astype(jnp.float32)
+    with jax.named_scope("dit.embed"):
+        x = x + params["final_b"].astype(x.dtype)
+        eps = unpatchify(cfg, x, hh, ww).astype(jnp.float32)
 
     new_drift = None
     if drift is not None:

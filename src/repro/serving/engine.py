@@ -568,8 +568,11 @@ class DriftServeEngine:
                          run_key=run_key)
 
     def _run_batch(self, mb: MicroBatch) -> List[RequestResult]:
-        ctx = self._prepare_batch(mb)
-        out = self.servable_for(mb.key.arch).execute(mb, ctx)
+        # host phases (docs/tracing.md): profiler annotations + phase spans
+        with self.tracer.phase("batch.prepare"):
+            ctx = self._prepare_batch(mb)
+        with self.tracer.phase("batch.sample"):
+            out = self.servable_for(mb.key.arch).execute(mb, ctx)
         return self._finish_batch(mb, ctx, out)
 
     def _run_batch_stream(self, mb: MicroBatch, preview_interval: int):
@@ -579,14 +582,17 @@ class DriftServeEngine:
         so a streamed request's result record is indistinguishable from an
         unstreamed one apart from having produced previews on the way.
         Paradigms without previews (autoregressive) raise a clear error."""
-        ctx = self._prepare_batch(mb)
+        with self.tracer.phase("batch.prepare"):
+            ctx = self._prepare_batch(mb)
         out = None
         sv = self.servable_for(mb.key.arch)
-        for ev in sv.execute_stream(mb, ctx, preview_interval):
-            if isinstance(ev, tuple) and ev and ev[0] == "final":
-                out = ev[1]
-                break           # terminating item; nothing follows
-            yield ev
+        # the phase stays open while the consumer handles each preview
+        with self.tracer.phase("batch.sample"):
+            for ev in sv.execute_stream(mb, ctx, preview_interval):
+                if isinstance(ev, tuple) and ev and ev[0] == "final":
+                    out = ev[1]
+                    break           # terminating item; nothing follows
+                yield ev
         assert out is not None, "servable stream ended without a final"
         yield from self._finish_batch(mb, ctx, out)
 
@@ -596,12 +602,21 @@ class DriftServeEngine:
         result records for a completed batch -- paradigm specifics come
         back from the servable as a ``BatchOutcome``."""
         key = mb.key
-        batch_index = ctx.batch_index
         protected = key.mode in _MONITORED_MODES
         if protected:
             self.monitor = out.monitor   # Sec 5.1 carry-over across batches
 
+        # finalize opens its own phases (batch.twin/sync/quality)
         outcome = self.servable_for(key.arch).finalize(mb, ctx, out)
+        with self.tracer.phase("batch.account"):
+            return self._account_batch(mb, ctx, outcome, protected)
+
+    def _account_batch(self, mb: MicroBatch, ctx: _BatchCtx, outcome,
+                       protected: bool) -> List[RequestResult]:
+        """Pricing, the virtual clock, result records, telemetry and the
+        recorder's batch close, from the servable's ``BatchOutcome``."""
+        key = mb.key
+        batch_index = ctx.batch_index
         # report the engine's post-batch state: for unmonitored modes the
         # sampler's internal EMA decays toward zero on no-detection steps,
         # which would misrepresent the actual error estimate

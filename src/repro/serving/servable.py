@@ -318,15 +318,21 @@ class DiffusionServable(ServableModel):
     def finalize(self, mb, ctx, out) -> BatchOutcome:
         from repro.serving.engine import OP_BY_NAME, _MONITORED_MODES
         key = mb.key
+        tracer = self.eng.tracer
         latents, cond, text = ctx.inputs
         img = jnp.clip(out.latents, -1, 1)
         if key.mode == "clean":
             clean = img       # the run IS the reference; don't jit a twin
         else:
-            clean = self._clean_reference(key, ctx.padded_seeds, ctx.params,
-                                          latents, cond, text)
-        corrected = int(out.total_corrected)
-        nevals = int(out.n_model_evals)
+            with tracer.phase("batch.twin"):
+                clean = self._clean_reference(key, ctx.padded_seeds,
+                                              ctx.params, latents, cond, text)
+        from repro.serving.trace import heatmap as heatmap_lib
+        with tracer.phase("batch.sync"):    # the first blocking reads
+            corrected = int(out.total_corrected)
+            nevals = int(out.n_model_evals)
+            heat, blocks = heatmap_lib.summarize(getattr(out, "heatmap",
+                                                         None))
         op_point = OP_BY_NAME.get(key.op, dvfs_lib.NOMINAL)
         # only protected modes pay ABFT compute + checkpoint DRAM traffic;
         # clean/faulty/float_clean run neither mechanism
@@ -341,14 +347,13 @@ class DiffusionServable(ServableModel):
             recovery_tiles_per_step=corrected / max(key.steps, 1)
             / (32 * 32))
         per_slot = []
-        for slot, req in enumerate(mb.requests):
-            a, b = img[slot:slot + 1], clean[slot:slot + 1]
-            per_slot.append(dict(
-                lpips_vs_clean=float(metrics.lpips_proxy(a, b)),
-                psnr_vs_clean_db=float(metrics.psnr(a, b)),
-                latents=a[0]))
-        from repro.serving.trace import heatmap as heatmap_lib
-        heat, blocks = heatmap_lib.summarize(getattr(out, "heatmap", None))
+        with tracer.phase("batch.quality"):
+            for slot, req in enumerate(mb.requests):
+                a, b = img[slot:slot + 1], clean[slot:slot + 1]
+                per_slot.append(dict(
+                    lpips_vs_clean=float(metrics.lpips_proxy(a, b)),
+                    psnr_vs_clean_db=float(metrics.psnr(a, b)),
+                    latents=a[0]))
         return BatchOutcome(
             corrected=corrected, n_model_evals=nevals, rc=rc,
             n_words=int(latents.size) * max(key.steps, 1),

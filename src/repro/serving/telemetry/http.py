@@ -305,9 +305,11 @@ class TelemetryHTTPServer:
         """The whole ring buffer as Chrome trace JSON (lock-free read:
         the recorder snapshots its deque under its own lock)."""
         tracer = getattr(self.engine, "tracer", None)
-        spans = tracer.spans() if tracer is not None else []
-        self._respond(h, 200, "application/json",
-                      json.dumps(to_chrome_trace(spans)))
+        if tracer is None:
+            doc = to_chrome_trace([])
+        else:
+            doc = to_chrome_trace(tracer.spans(), tracer.epoch_unix_ns)
+        self._respond(h, 200, "application/json", json.dumps(doc))
 
     def _trace(self, h, tail: str) -> None:
         """``GET /trace/<request_id>``: one request's span tree, or 404

@@ -11,12 +11,15 @@ Wall-clock timestamps drive ``ts``/``dur`` (that is what a trace viewer
 lays out); the virtual-clock interval and every span attr ride along in
 ``args`` so the perfmodel story stays reconstructible from the file.
 Spans are grouped one ``tid`` per batch (``tid 0`` for pre-batch spans
-like submit/admission), all under a single ``pid``.
+like submit/admission), all under a single ``pid``. ``ts`` counts from the
+recorder's epoch; ``otherData.epoch_unix_ns`` is that epoch on the
+profiler's host clock, so ``epoch_unix_ns + ts * 1000`` lines a span up
+with a ``jax.profiler`` trace of the same run.
 """
 from __future__ import annotations
 
 import json
-from typing import Any, Dict, Iterable, List
+from typing import Any, Dict, Iterable, List, Optional
 
 from repro.version import __version__
 
@@ -44,7 +47,8 @@ def span_to_event(span: Span) -> Dict[str, Any]:
     }
 
 
-def to_chrome_trace(spans: Iterable[Span]) -> Dict[str, Any]:
+def to_chrome_trace(spans: Iterable[Span],
+                    epoch_unix_ns: Optional[int] = None) -> Dict[str, Any]:
     spans = list(spans)
     events: List[Dict[str, Any]] = [{
         "name": "process_name", "ph": "M", "pid": _PID,
@@ -57,12 +61,16 @@ def to_chrome_trace(spans: Iterable[Span]) -> Dict[str, Any]:
                      else f"batch {bi}"},
         })
     events.extend(span_to_event(s) for s in spans)
-    return {"traceEvents": events, "displayTimeUnit": "ms"}
+    doc = {"traceEvents": events, "displayTimeUnit": "ms"}
+    if epoch_unix_ns is not None:
+        doc["otherData"] = {"epoch_unix_ns": int(epoch_unix_ns)}
+    return doc
 
 
-def write_chrome_trace(path: str, spans: Iterable[Span]) -> None:
+def write_chrome_trace(path: str, spans: Iterable[Span],
+                       epoch_unix_ns: Optional[int] = None) -> None:
     with open(path, "w") as f:
-        json.dump(to_chrome_trace(spans), f, indent=1)
+        json.dump(to_chrome_trace(spans, epoch_unix_ns), f, indent=1)
 
 
 def request_tree(spans: Iterable[Span], request_id: int) -> Dict[str, Any]:

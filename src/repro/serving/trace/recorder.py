@@ -13,7 +13,14 @@ is recorded as a :class:`Span` carrying BOTH clocks:
   as attrs (e.g. the batch's modeled ``latency_s``) rather than faked.
 * ``wall_s`` -- host ``time.perf_counter`` relative to the recorder's
   epoch. Real durations: compile cost, window cadence, offload commit
-  latency.
+  latency. The epoch is also taken on the profiler's host clock
+  (``epoch_unix_ns``, Unix nanoseconds), so :meth:`FlightRecorder.profiler_ns`
+  puts any ``wall_s`` on a ``jax.profiler`` trace's timeline.
+
+:meth:`FlightRecorder.phase` names a stretch of the engine's host work
+(``batch.prepare``, ``batch.sample``, ...): it opens a
+``jax.profiler.TraceAnnotation`` of the same name, so a profile shows the
+phase on the device trace's clock, and records a ``phase`` span.
 
 The recorder is **zero-perturbation by construction**: every hook runs
 host-side between traced computations (the heatmap the detect spans
@@ -28,10 +35,13 @@ the newest ``capacity`` spans and counts what it dropped.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # Span kinds, the full taxonomy (docs/tracing.md documents each):
 SPAN_KINDS = (
@@ -46,6 +56,7 @@ SPAN_KINDS = (
     "replay",            # rollback replay (AR window re-decode)
     "detect",            # per-batch detection summary (heatmap attrs)
     "finalize",          # quality/energy attribution, results built
+    "phase",             # a named stretch of the engine's host work
 )
 
 
@@ -78,6 +89,9 @@ class FlightRecorder:
         self._ring: collections.deque = collections.deque(maxlen=capacity)
         self.recorded = 0
         self.dropped = 0
+        # one instant on both clocks: perf_counter for the spans, Unix
+        # nanoseconds for the profiler's host timeline
+        self.epoch_unix_ns = time.time_ns()
         self._epoch = time.perf_counter()
         # Current-batch context (the engine is single-threaded between
         # batches; only offload commits arrive from another thread, and
@@ -95,6 +109,12 @@ class FlightRecorder:
     # ------------------------------------------------------------ plumbing
     def now_wall(self) -> float:
         return time.perf_counter() - self._epoch
+
+    def profiler_ns(self, wall_s: float) -> int:
+        """A span's ``wall_s`` on the profiler's host clock (Unix ns). An
+        ``.xplane.pb`` stores its times relative to the ``Task
+        Environment`` plane's ``profile_start_time``; subtract that."""
+        return self.epoch_unix_ns + round(wall_s * 1e9)
 
     def record(self, name: str, kind: str, request_ids=(),
                batch_index: int = -1,
@@ -218,6 +238,24 @@ class FlightRecorder:
                     batch_index=bi, t0_virtual_s=v,
                     window_start=int(window_start),
                     window_len=int(window_len), **attrs)
+
+    @contextlib.contextmanager
+    def phase(self, name: str, **attrs):
+        """Name a stretch of host work: a profiler annotation ``name``
+        (recorded while a ``jax.profiler`` trace runs, enabled or not) and,
+        when enabled, a ``phase`` span tied to the current batch. The span
+        is recorded at exit, so a phase that opens the batch
+        (``batch.prepare``) is tied to it."""
+        t0 = self.now_wall()
+        with TraceAnnotation(name):
+            yield
+        if not self.enabled:
+            return
+        with self._lock:
+            bi, rids, v = (self._batch_index, self._batch_request_ids,
+                           self._batch_virtual_s)
+        self.record(name, "phase", request_ids=rids, batch_index=bi,
+                    t0_virtual_s=v, t0_wall_s=t0, **attrs)
 
     def finish_batch(self, virtual_t1_s: float, detect_attrs=None,
                      **finalize_attrs) -> None:

@@ -90,6 +90,8 @@ def test_recorder_disabled_is_a_noop():
     rec.on_offload("commit", 0, 0.01, nbytes=8)
     rec.on_replay(0, 4)
     rec.finish_batch(1.0, detect_attrs={"heatmap": ((1,),)})
+    with rec.phase("batch.sample"):
+        pass
     assert len(rec) == 0 and rec.recorded == 0
 
 
@@ -117,7 +119,7 @@ def test_span_kinds_taxonomy_is_closed():
     assert set(SPAN_KINDS) == {
         "submit", "admission", "queue_wait", "batch_assembly", "compile",
         "window", "offload_commit", "offload_restore", "replay", "detect",
-        "finalize"}
+        "finalize", "phase"}
 
 
 # ------------------------------------------------------- heatmap plumbing
@@ -352,7 +354,10 @@ def test_chrome_trace_export_shape():
     assert submit["tid"] == 0              # pre-batch track
     window = next(e for e in spans if e["cat"] == "window")
     assert window["tid"] == 4              # batch 3 -> tid 4
+    assert "otherData" not in doc
     json.dumps(doc)                        # wire-serializable
+    doc = to_chrome_trace(rec.spans(), rec.epoch_unix_ns)
+    assert doc["otherData"] == {"epoch_unix_ns": rec.epoch_unix_ns}
 
 
 def test_request_tree_shape():
@@ -537,6 +542,108 @@ def test_build_info_uptime_and_heatmap_metrics():
         if line.startswith("drift_detect_heatmap_total{"):
             total += float(line.rsplit(" ", 1)[1])
     assert total == sum(map(sum, res.detect_heatmap)) > 0
+
+
+# ------------------------------------- phases on the profiler's clock
+PHASES = ("batch.prepare", "batch.sample", "batch.twin", "batch.sync",
+          "batch.quality", "batch.account")
+
+
+@pytest.fixture(scope="module")
+def profiled_drift_batch(tmp_path_factory):
+    """A warm smoke drift batch served under ``jax.profiler`` on the CPU:
+    (engine, the trace's ProfileData)."""
+    from jax.profiler import ProfileData
+    eng = _engine()
+    eng.submit(steps=3, mode="drift", op="undervolt", seed=0)
+    eng.run()                       # compiles the sampler and its twin
+    trace_dir = tmp_path_factory.mktemp("profile")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        # a fresh seed, so the twin runs instead of hitting its cache
+        eng.submit(steps=3, mode="drift", op="undervolt", seed=1)
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = trace_dir.glob("plugins/profile/*/*.xplane.pb")
+    return eng, ProfileData.from_file(str(path))
+
+
+def test_drift_batch_records_phase_spans_in_order(profiled_drift_batch):
+    eng, _ = profiled_drift_batch
+    for batch in (0, 1):
+        phases = [s for s in eng.tracer.spans()
+                  if s.kind == "phase" and s.batch_index == batch]
+        assert tuple(s.name for s in phases) == PHASES
+        assert all(s.request_ids == (batch,) for s in phases)
+        assert all(s.t1_wall_s >= s.t0_wall_s for s in phases)
+        assert all(a.t1_wall_s <= b.t0_wall_s
+                   for a, b in zip(phases, phases[1:]))
+
+
+def test_phase_annotations_on_the_profiler_clock(profiled_drift_batch):
+    """Each ``batch.*`` annotation on the host plane matches its ring span
+    mapped through the recorder's epoch to within 1 ms, and the device
+    work of the batch runs under the mode-named samplers."""
+    eng, data = profiled_drift_batch
+    start_ns = next(dict(p.stats)["profile_start_time"] for p in data.planes
+                    if p.name == "Task Environment")
+    annotations, modules = [], set()
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("batch."):
+                    annotations.append((ev.start_ns,
+                                        ev.start_ns + ev.duration_ns,
+                                        ev.name))
+                modules.add(dict(ev.stats).get("hlo_module"))
+    annotations.sort()
+    assert tuple(n for _, _, n in annotations) == PHASES
+    phases = [s for s in eng.tracer.spans()
+              if s.kind == "phase" and s.batch_index == 1]
+    for (t0, t1, name), span in zip(annotations, phases):
+        assert span.name == name
+        for wall_s, at in ((span.t0_wall_s, t0), (span.t1_wall_s, t1)):
+            mapped = eng.tracer.profiler_ns(wall_s) - start_ns
+            assert abs(mapped - at) < 1e6, (name, mapped, at)
+    assert {"jit_sample_drift", "jit_sample_clean"} <= modules
+
+
+def test_drift_sampler_hlo_names_protection_scopes():
+    """The compiled smoke drift sampler carries each protection phase's
+    scope and the attention scope in its op_name metadata, under the
+    mode-named module."""
+    import re
+
+    from repro import configs
+    from repro.core import dvfs
+    from repro.core.exec_ctx import DriftSystemConfig
+    from repro.diffusion import sampler as sampler_lib
+    from repro.serving.engine import OP_BY_NAME
+    from repro.train import steps as steps_lib
+
+    cfg = configs.get_config(ARCH, smoke=True)
+    scfg = sampler_lib.SamplerConfig(
+        num_sample_steps=3, drift=DriftSystemConfig(mode="drift"),
+        schedule=dvfs.fine_grained_schedule(3, OP_BY_NAME["undervolt"],
+                                            nominal_steps=1))
+    fn = sampler_lib.make_sampler(cfg, scfg)
+    shape = (1, cfg.latent_size, cfg.latent_size, cfg.latent_channels)
+    params = steps_lib.init_model_params(cfg, jax.random.PRNGKey(0))
+    hlo = fn.lower(params, jax.random.PRNGKey(1), jax.numpy.zeros(shape),
+                   jax.numpy.zeros((1,), "int32"), None,
+                   dvfs.ber_monitor_init()).compile().as_text()
+    assert "jit_sample_drift" in hlo
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+    scopes = {part for name in op_names for part in name.split("/")}
+    assert {"drift.quantize", "drift.gemm", "drift.inject", "drift.checksum",
+            "drift.correct", "drift.ckpt", "dit.attention", "dit.modulation",
+            "sampler.step"} <= scopes
 
 
 # ------------------------------------------------------------------ mesh
