@@ -26,9 +26,18 @@ Tiled variant
 -------------
 The paper's recovery is tile-by-tile (Sec 5.4); ``tile_checksum_diff``
 evaluates per-(tile-row, tile-col) checksums so the correction mask and the
-DRAM-row accounting operate at tile granularity. The Pallas kernel in
-``repro.kernels.abft_matmul`` fuses these per-tile sums into the GEMM
-epilogue; this module is the pure-jnp oracle and the small-shape fallback.
+DRAM-row accounting operate at tile granularity. The sums are taken in the
+accumulator's own (M, N) layout: per row and column tile (M, N/tile_n),
+per column and row tile (M/tile_m, N). Splitting the minor dimension into
+32-wide tiles would be a relayout of the whole accumulator on the TPU's
+128-lane vregs. So the column-tile sums reduce a split of the row
+dimension, and the row-tile sums are a product with a 0/1 block indicator
+on the MXU, the int32 accumulator entering as four exact int8 digits.
+``tile_error_mask`` expands the row and column flags through indicators
+too, and counts the masked elements from the flags. Drift mode takes its
+whole-row error count from the tile row sums (``tile_row_errors``), so the
+accumulator is read by one checksum pass, not two. The Pallas kernel in
+``repro.kernels.abft_matmul`` fuses per-tile sums into the GEMM epilogue.
 """
 from __future__ import annotations
 
@@ -153,52 +162,131 @@ def _pad_to(x: jax.Array, m: int, axis: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
+def _block_indicator(n: int, t: int) -> jax.Array:
+    """(n, n // t) int8 0/1 matrix: entry (i, b) is 1 iff i // t == b."""
+    return (jnp.arange(n)[:, None] // t
+            == jnp.arange(n // t)[None, :]).astype(jnp.int8)
+
+
+def _digit_count(bound: int) -> int:
+    """Balanced int8 digits needed for every integer of magnitude <= bound
+    (four hold any int32 mod 2^32)."""
+    count = 1
+    while count < 4 and 127 * (256 ** count - 1) // 255 < bound:
+        count += 1
+    return count
+
+
+def _digits(x: jax.Array, count: int):
+    """``count`` int8 digits d_j with x == sum_j d_j * 2^(8j), mod 2^32.
+
+    Each digit is the sign-extended low byte of the running remainder, so
+    it lies in [-128, 127] and the remainder less the digit shifts down
+    exactly; wrapping int32 keeps every step congruent mod 2^32."""
+    digits = []
+    for _ in range(count):
+        d = (x << 24) >> 24
+        digits.append(d.astype(jnp.int8))
+        x = (x - d) >> 8
+    return digits
+
+
+def _wide_dot(lhs: jax.Array, rhs: jax.Array, wide: str, count: int = 4
+              ) -> jax.Array:
+    """lhs @ rhs in wrapping int32, where the ``wide`` operand ("lhs" or
+    "rhs") is int32 and the other int8: one int8 MXU pass per int8 digit
+    of the wide operand, recombined mod 2^32. Exact."""
+    out = None
+    for j, d in enumerate(_digits(lhs if wide == "lhs" else rhs, count)):
+        part = jnp.dot(d if wide == "lhs" else lhs,
+                       rhs if wide == "lhs" else d,
+                       preferred_element_type=jnp.int32) << (8 * j)
+        out = part if out is None else out + part
+    return out
+
+
 def tile_checksum_diff(acc: jax.Array, aq: jax.Array, bq: jax.Array,
                        cfg: AbftConfig) -> Tuple[jax.Array, jax.Array]:
-    """Per-tile checksum differences.
+    """Per-tile checksum differences, in the accumulator's own layout.
 
-    Returns (row_diff_t, col_diff_t):
-      row_diff_t: (Mt, Nt, tile_m) -- per tile, per local row
-      col_diff_t: (Mt, Nt, tile_n) -- per tile, per local col
-    where Mt = ceil(M/tile_m), Nt = ceil(N/tile_n). Exact int32 arithmetic.
+    Returns (row_diff, col_diff):
+      row_diff: (Mp, Nt) -- per row, summed over each column tile
+      col_diff: (Mt, Np) -- per column, summed over each row tile
+    where Mt = ceil(M/tile_m), Nt = ceil(N/tile_n), Mp = Mt*tile_m and
+    Np = Nt*tile_n (padded rows and columns read zero). Exact int32
+    arithmetic.
+
+    No intermediate has a tile-wide minor dimension, which on the TPU's
+    128-lane vregs would relayout the whole accumulator. The column sums
+    reduce a split of the row (sublane) dimension, which is free. The row
+    sums and the expected sides are products with 0/1 block indicators on
+    the MXU, their int32 operands entering as int8 digits (``_wide_dot``).
     """
-    m, n = acc.shape
-    k = aq.shape[1]
     tm, tn = cfg.tile_m, cfg.tile_n
-    a32 = _pad_to(aq.astype(jnp.int32), tm, 0)
-    b32 = _pad_to(bq.astype(jnp.int32), tn, 1)
+    aqp = _pad_to(aq, tm, 0)
+    bqp = _pad_to(bq, tn, 1)
     accp = _pad_to(_pad_to(acc, tm, 0), tn, 1)
-    mt, nt = accp.shape[0] // tm, accp.shape[1] // tn
-    acc_t = accp.reshape(mt, tm, nt, tn)
+    ind_m = _block_indicator(accp.shape[0], tm)               # (Mp, Mt)
+    ind_n = _block_indicator(accp.shape[1], tn)               # (Np, Nt)
 
-    # Expected per-tile row sums: A_tile @ (B col-block row-sum)
-    b_blocksum = b32.reshape(k, nt, tn).sum(axis=2)          # (K, Nt)
-    exp_row = jnp.einsum("mk,kn->mn", a32, b_blocksum,
-                         preferred_element_type=jnp.int32)    # (Mp, Nt)
-    exp_row_t = exp_row.reshape(mt, tm, nt).transpose(0, 2, 1)  # (Mt, Nt, tm)
-    act_row_t = acc_t.sum(axis=3).transpose(0, 2, 1)            # (Mt, Nt, tm)
+    # Expected: A @ (B's column-tile row sums), (A's row-tile column sums) @ B
+    b_blocksum = jnp.dot(bqp, ind_n, preferred_element_type=jnp.int32)
+    a_blocksum = jnp.dot(ind_m.T, aqp, preferred_element_type=jnp.int32)
+    exp_row = _wide_dot(aqp, b_blocksum, "rhs",
+                        _digit_count(128 * tn))               # (Mp, Nt)
+    exp_col = _wide_dot(a_blocksum, bqp, "lhs",
+                        _digit_count(128 * tm))               # (Mt, Np)
 
-    a_blocksum = a32.reshape(mt, tm, k).sum(axis=1)          # (Mt, K)
-    exp_col = jnp.einsum("mk,kn->mn", a_blocksum, b32,
-                         preferred_element_type=jnp.int32)    # (Mt, Np)
-    exp_col_t = exp_col.reshape(mt, nt, tn)                   # (Mt, Nt, tn)
-    act_col_t = acc_t.sum(axis=1).reshape(mt, nt, tn)
-
-    return act_row_t - exp_row_t, act_col_t - exp_col_t
+    act_row = _wide_dot(accp, ind_n, "lhs")                   # (Mp, Nt)
+    act_col = accp.reshape(ind_m.shape[1], tm, -1).sum(axis=1)  # (Mt, Np)
+    return act_row - exp_row, act_col - exp_col
 
 
-def tile_error_mask(row_diff_t: jax.Array, col_diff_t: jax.Array,
-                    cfg: AbftConfig, out_shape: Tuple[int, int]) -> Tuple[jax.Array, jax.Array]:
-    """Element mask (M, N) + per-tile flag (Mt, Nt) from tile checksums."""
-    thr = jnp.int32(cfg.threshold) if row_diff_t.dtype == jnp.int32 else jnp.float32(cfg.threshold)
-    row_flag = _exceeds(row_diff_t, thr)                      # (Mt, Nt, tm)
-    col_flag = _exceeds(col_diff_t, thr)                      # (Mt, Nt, tn)
+def tile_row_errors(row_diff: jax.Array, cfg: AbftConfig) -> jax.Array:
+    """Rows whose whole-row checksum difference passes the threshold.
+
+    A row's full difference is the wrapping sum of its tile differences,
+    so this is ``detect_int(...).n_row_err`` without a second pass over
+    the accumulator."""
+    thr = jnp.int32(cfg.threshold)
+    return jnp.sum(_exceeds(row_diff.sum(axis=1), thr).astype(jnp.int32))
+
+
+def tile_error_mask(row_diff: jax.Array, col_diff: jax.Array,
+                    cfg: AbftConfig, out_shape: Tuple[int, int]
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Element mask (M, N), per-tile flag (Mt, Nt) and the number of masked
+    elements, from tile checksums.
+
+    The row flags (Mp, Nt) and column flags (Mt, Np) expand to (Mp, Np)
+    without a tile-wide minor dimension, through 0/1 block indicators in
+    one MXU pass that counts each element's flagged row and column. The
+    count comes from the flags' per-tile counts, so only the mask's one
+    consumer reads the full mask.
+    """
+    thr = (jnp.int32(cfg.threshold) if row_diff.dtype == jnp.int32
+           else jnp.float32(cfg.threshold))
+    m, n = out_shape
+    tm, tn = cfg.tile_m, cfg.tile_n
+    row_flag = _exceeds(row_diff, thr)                        # (Mp, Nt)
+    col_flag = _exceeds(col_diff, thr)                        # (Mt, Np)
+    (mp, nt), (mt, np_) = row_flag.shape, col_flag.shape
+    # hits[i, j] = row_flag[i, j // tn] + col_flag[i // tm, j], in one dot
+    lhs = jnp.concatenate([row_flag, _block_indicator(mp, tm)], axis=1)
+    rhs = jnp.concatenate([_block_indicator(np_, tn).T, col_flag], axis=0)
+    hits = jnp.dot(lhs.astype(jnp.bfloat16), rhs.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+    # flagged rows / columns per tile; padded rows and columns read zero
+    # difference, so they are never flagged
+    n_rows = row_flag.reshape(mt, tm, nt).sum(axis=1, dtype=jnp.int32)
+    n_cols = col_flag.reshape(mt, nt, tn).sum(axis=2, dtype=jnp.int32)
     if cfg.mask_policy == "cross":
-        mask_t = row_flag[:, :, :, None] & col_flag[:, :, None, :]
+        mask = hits > 1
+        per_tile = n_rows * n_cols
     else:  # union
-        mask_t = row_flag[:, :, :, None] | col_flag[:, :, None, :]
-    tile_flag = jnp.any(mask_t, axis=(2, 3))                  # (Mt, Nt)
-    mt, nt, tm = row_flag.shape
-    tn = col_flag.shape[2]
-    mask = mask_t.transpose(0, 2, 1, 3).reshape(mt * tm, nt * tn)
-    return mask[: out_shape[0], : out_shape[1]], tile_flag
+        mask = hits > 0
+        tile_rows = jnp.clip(m - tm * jnp.arange(mt), 0, tm)[:, None]
+        tile_cols = jnp.clip(n - tn * jnp.arange(nt), 0, tn)[None, :]
+        per_tile = (n_rows * tile_cols + n_cols * tile_rows
+                    - n_rows * n_cols)
+    return mask[:m, :n], per_tile > 0, jnp.sum(per_tile)

@@ -135,28 +135,33 @@ class ExecContext:
                 y = quant.dequantize_matmul(acc_faulty, xq.scale, w_scale)
                 return y.reshape(*lead, n).astype(x.dtype)
 
-        with jax.named_scope("drift.checksum"):
-            report = abft_lib.detect_int(acc_faulty, xq.q, wq_q,
-                                         self.cfg.abft)
-            self._bump("detected_row_errors", report.n_row_err)
-            self._bump("gemm_words", jnp.int32(acc.size))
-        with jax.named_scope("drift.gemm"):
-            y_faulty = quant.dequantize_matmul(acc_faulty, xq.scale, w_scale)
-
         if self.cfg.mode == "drift":
             # Tile-granular recovery (Sec 5.4): the recovery scheduler works
             # tile-by-tile, so the row x col cross-combine happens *within*
             # each systolic tile -- far sparser masks than a full-matrix
             # cross at high BER, and exactly what the Pallas kernel emits.
+            # The whole-row count comes from the same tile sums.
             with jax.named_scope("drift.checksum"):
                 rd, cd = abft_lib.tile_checksum_diff(acc_faulty, xq.q, wq_q,
                                                      self.cfg.abft)
-                mask, tile_flag = abft_lib.tile_error_mask(
+                n_row_err = abft_lib.tile_row_errors(rd, self.cfg.abft)
+                mask, tile_flag, n_corr = abft_lib.tile_error_mask(
                     rd, cd, self.cfg.abft, acc.shape)
+        else:
+            with jax.named_scope("drift.checksum"):
+                report = abft_lib.detect_int(acc_faulty, xq.q, wq_q,
+                                             self.cfg.abft)
+                n_row_err = report.n_row_err
+        with jax.named_scope("drift.checksum"):
+            self._bump("detected_row_errors", n_row_err)
+            self._bump("gemm_words", jnp.int32(acc.size))
+        with jax.named_scope("drift.gemm"):
+            y_faulty = quant.dequantize_matmul(acc_faulty, xq.scale, w_scale)
+
+        if self.cfg.mode == "drift":
             with jax.named_scope("drift.correct"):
                 ckpt = self.state_in.get(name)
                 y = rollback.correct(y_faulty, ckpt, mask, self.have_ckpt)
-                n_corr = jnp.sum(mask.astype(jnp.int32))
                 # DRAM cost: one repacked-tile read per flagged tile.
                 tile_bytes = self.cfg.abft.tile_m * self.cfg.abft.tile_n * 4
                 cost = baselines.RecoveryCost(
@@ -183,8 +188,8 @@ class ExecContext:
             with jax.named_scope("drift.checksum"):
                 rd, cd = abft_lib.tile_checksum_diff(acc_faulty, xq.q, wq_q,
                                                      self.cfg.abft)
-                _, tile_flag = abft_lib.tile_error_mask(rd, cd, self.cfg.abft,
-                                                        acc.shape)
+                _, tile_flag, _ = abft_lib.tile_error_mask(
+                    rd, cd, self.cfg.abft, acc.shape)
             with jax.named_scope("drift.correct"):
                 y, cost = baselines.stat_abft(
                     y_clean, y_faulty, tile_flag,
