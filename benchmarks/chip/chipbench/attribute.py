@@ -23,11 +23,11 @@ A fusion carries the metadata of its root instruction and is charged to
 that scope. An op without a scope is charged to ``other``. Lookups are
 cached per module and distinct event name.
 
-:func:`metrics` turns that into per-layer numbers over a window: the twin's
-share of busy time, the device's idle time inside the engine's host phases
-per batch, and the busy time under the protection and attention scopes per
-image and denoising step. Busy time is a union of op intervals clipped to
-the window and averaged over the devices used, as in
+:func:`window_scopes` turns that into what the per-layer readers
+(``chipbench/metrics/``) take: the window's busy seconds by innermost scope
+and by module, and the device's idle time inside the engine's host phases
+with the number of batches they hold. Busy time is a union of op intervals
+clipped to the window and averaged over the devices used, as in
 :func:`chipbench.trace.reduce`; control flow and asynchronous ``*-start``
 events are not ops here either.
 """
@@ -43,19 +43,12 @@ from chipbench import trace as trace_lib
 
 OTHER = "other"
 MODULES_LINE = "XLA Modules"
+DRIFT_MODULE = "jit_sample_drift"
 TWIN_MODULE = "jit_sample_clean"
 PHASE_PREFIX = "batch."
 _SCOPE = re.compile(r"^(?:(?:drift|dit)\.(?!py$)[A-Za-z_]+|sampler\.step)$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _MODULE_ID = re.compile(r"\(\d+\)$")
-
-# metric name -> the scopes whose busy time it counts, per image-step
-SCOPE_METRICS = {
-    "inject_ms_per_image_step": ("drift.inject",),
-    "checksum_ms_per_image_step": ("drift.checksum",),
-    "rollback_ms_per_image_step": ("drift.correct", "drift.ckpt"),
-    "attention_ms_per_image_step": ("dit.attention",),
-}
 
 
 class Op(NamedTuple):
@@ -279,7 +272,8 @@ def _length(intervals) -> float:
 
 def busy_by(att: Attributed, window: trace_lib.Interval, key
             ) -> Dict[object, float]:
-    """Busy seconds in ``window`` by ``key(op)``, averaged over devices."""
+    """Busy seconds in ``window`` by ``key(op)``, averaged over devices;
+    a key with no time in the window is left out."""
     lo, hi = window
     planes = _planes(att)
     out: Dict[object, float] = collections.Counter()
@@ -290,28 +284,11 @@ def busy_by(att: Attributed, window: trace_lib.Interval, key
         for k, iv in groups.items():
             out[k] += _length(trace_lib.union(trace_lib.clip(iv, lo, hi)))
     n = max(len(planes), 1)
-    return {k: v * 1e-9 / n for k, v in out.items()}
+    return {k: v * 1e-9 / n for k, v in out.items() if v > 0}
 
 
 def busy_s(att: Attributed, window: trace_lib.Interval) -> float:
     return sum(busy_by(att, window, lambda o: None).values())
-
-
-def idle_in_spans_s(att: Attributed, window: trace_lib.Interval,
-                    spans: List[trace_lib.Interval]) -> float:
-    """Seconds of ``window`` inside ``spans`` in which no op ran, averaged
-    over devices."""
-    lo, hi = window
-    inside = trace_lib.union(trace_lib.clip(spans, lo, hi))
-    planes = _planes(att)
-    total = 0.0
-    for ops in planes:
-        busy = trace_lib.union(trace_lib.clip(
-            [(o.start, o.end) for o in ops], lo, hi))
-        for s, e in inside:
-            total += _length(trace_lib.gaps(trace_lib.clip(busy, s, e),
-                                            s, e))
-    return total * 1e-9 / max(len(planes), 1)
 
 
 def phase_spans(att: Attributed, window: trace_lib.Interval,
@@ -324,26 +301,85 @@ def phase_spans(att: Attributed, window: trace_lib.Interval,
             and lo <= s < hi]
 
 
-def metrics(att: Attributed, window: trace_lib.Interval, images: int,
-            steps: int) -> Dict[str, Optional[float]]:
-    """The per-layer numbers of ``window``; None where nothing is there to
-    read (no device op, no phase span, no image)."""
-    total = busy_s(att, window)
-    if total <= 0:
-        return {}
-    out: Dict[str, Optional[float]] = {}
-    by_module = busy_by(att, window, lambda o: o.module)
-    out["twin_share"] = (100.0 * by_module[TWIN_MODULE] / total
-                         if TWIN_MODULE in by_module else None)
-    batches = len(phase_spans(att, window, PHASE_PREFIX + "prepare"))
-    out["engine_idle_ms_per_batch"] = (
-        1e3 * idle_in_spans_s(att, window, phase_spans(att, window))
-        / batches if batches else None)
-    for name, scopes in SCOPE_METRICS.items():
-        secs = busy_by(att, window, lambda o: o.scope in scopes).get(True)
-        out[name] = (1e3 * secs / (images * steps)
-                     if secs is not None and images and steps else None)
-    return out
+@dataclasses.dataclass(frozen=True)
+class Scopes:
+    """What a traced window's device time was spent on."""
+    busy_s: float                   # busy seconds, all ops
+    by_scope: Dict[str, float]      # busy seconds by innermost scope
+    by_module: Dict[str, float]     # busy seconds by module
+    phase_idle_s: float             # idle seconds inside ``batch.*`` spans
+    batches: int                    # ``batch.prepare`` spans
+
+    def ms_per_image_step(self, scopes, images: int, steps: int
+                          ) -> Optional[float]:
+        """Busy milliseconds under ``scopes`` per image and step; None
+        where none of them ran or nothing was served."""
+        found = [self.by_scope[s] for s in scopes if s in self.by_scope]
+        if not found or not images or not steps:
+            return None
+        return 1e3 * sum(found) / (images * steps)
+
+
+def _overlap(a: List[trace_lib.Interval], b: List[trace_lib.Interval]
+             ) -> float:
+    """Length of the intersection of two sorted, disjoint interval lists."""
+    total, i, j = 0.0, 0, 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if hi > lo:
+            total += hi - lo
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
+def window_scopes(att: Attributed, window: trace_lib.Interval) -> Scopes:
+    """The busy time of ``window`` by scope and by module, and the device's
+    idle time inside the engine's phases, in one pass over each device's
+    ops in start order: each key's intervals are merged as they come, so
+    every number is a union, as :func:`busy_by` takes it."""
+    lo, hi = window
+    phases = trace_lib.union(trace_lib.clip(phase_spans(att, window),
+                                            lo, hi))
+    phase_ns = _length(phases)
+    totals: Dict[object, float] = collections.Counter()
+    idle_ns = 0.0
+    planes = _planes(att)
+    for ops in planes:
+        open_iv: Dict[object, list] = {}
+        busy: List[list] = []
+        for o in sorted(ops, key=lambda o: o.start):
+            start, end = max(o.start, lo), min(o.end, hi)
+            if end <= start:
+                continue
+            for key in (("scope", o.scope), ("module", o.module)):
+                cur = open_iv.get(key)
+                if cur is not None and start <= cur[1]:
+                    cur[1] = max(cur[1], end)
+                    continue
+                if cur is not None:
+                    totals[key] += cur[1] - cur[0]
+                open_iv[key] = [start, end]
+            if busy and start <= busy[-1][1]:
+                busy[-1][1] = max(busy[-1][1], end)
+            else:
+                busy.append([start, end])
+        for key, (start, end) in open_iv.items():
+            totals[key] += end - start
+        totals[("all", None)] += _length(busy)
+        idle_ns += phase_ns - _overlap(busy, phases)
+    n = max(len(planes), 1)
+
+    def seconds(kind):
+        return {k: v * 1e-9 / n for (c, k), v in totals.items()
+                if c == kind and v > 0}
+    return Scopes(
+        busy_s=totals[("all", None)] * 1e-9 / n,
+        by_scope=seconds("scope"), by_module=seconds("module"),
+        phase_idle_s=idle_ns * 1e-9 / n,
+        batches=len(phase_spans(att, window, PHASE_PREFIX + "prepare")))
 
 
 def breakdown(att: Attributed, window: trace_lib.Interval, top: int = 10

@@ -38,6 +38,11 @@ The projection GEMMs are computed in one of three ways (``Precision``):
 
 Everything else (layer norms, softmax, GELU, modulation, DDIM) is float32
 at the highest precision in every mode.
+
+As every configuration's reference module, it also states the
+architecture to the rest of the harness: :func:`param_shapes`, the served
+parameter tree that ``chipbench.weights`` draws, and :func:`per_eval`, the
+operations of one denoiser evaluation that ``chipbench.opcount`` prices.
 """
 from __future__ import annotations
 
@@ -50,6 +55,8 @@ from typing import Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chipbench.weights import SINCOS
 
 HI = jax.lax.Precision.HIGHEST
 EMBED_LAYER = 1000      # fold-in tag of the embedding GEMMs' fault key
@@ -75,6 +82,76 @@ class Batch:
     batch_index: int        # the engine's batch counter
     engine_seed: int        # the engine's base seed
     ber: float              # body-block BER at the batch's operating point
+
+
+# Scale of the leaves a trained DiT starts from zero (adaLN, output
+# projection, biases) and of the class embedding.
+SMALL_STD = 0.02
+
+
+# ------------------------------------------------------------ architecture
+def param_shapes(cfg: dict) -> Dict:
+    """{leaf path: (shape, std or ``SINCOS``)} of the served tree.
+
+    The layout is the one the served model reads (``params["blocks"]``
+    stacked over depth, as ``models/dit.py`` keeps it), from the
+    configuration's sizes alone. Every leaf but the position embedding is
+    drawn, the adaLN-Zero modulation and output projections and the biases
+    included: zero-initialised modulation makes every block an identity
+    and the predicted noise exactly zero, which no trained checkpoint has.
+    The position embedding is DiT's frozen 2-D sine-cosine table
+    (arXiv:2212.09748, Sec. 3.2), as every DiT checkpoint carries it: a
+    small drawn one leaves each token's state to the image alone, and the
+    sampler then amplifies float rounding into the finals as no trained
+    model does."""
+    d, f, n_layers = cfg["d_model"], cfg["d_ff"], cfg["n_layers"]
+    tokens = (cfg["latent_size"] // cfg["patch_size"]) ** 2
+    pdim = cfg["patch_size"] ** 2 * cfg["latent_channels"]
+
+    def dense(k, n):
+        return ((k, n), 1.0 / math.sqrt(k))
+
+    def stacked(leaf):
+        shape, std = leaf
+        return ((n_layers,) + shape, std)
+
+    attn = {"wq": stacked(dense(d, d)), "wk": stacked(dense(d, d)),
+            "wv": stacked(dense(d, d)), "wo": stacked(dense(d, d))}
+    blocks = {"adaln_w": stacked(((d, 6 * d), SMALL_STD)),
+              "adaln_b": stacked(((6 * d,), SMALL_STD)),
+              "attn": attn,
+              "mlp_w1": stacked(dense(d, f)),
+              "mlp_w2": stacked(dense(f, d))}
+    tree = {
+        "patch_w": dense(pdim, d), "patch_b": ((d,), SMALL_STD),
+        "pos_embed": ((tokens, d), SINCOS),
+        "t_w1": dense(256, d), "t_b1": ((d,), SMALL_STD),
+        "t_w2": dense(d, d), "t_b2": ((d,), SMALL_STD),
+        "blocks": blocks,
+        "final_adaln_w": ((d, 2 * d), SMALL_STD),
+        "final_adaln_b": ((2 * d,), SMALL_STD),
+        "final_w": ((d, pdim), SMALL_STD), "final_b": ((pdim,), SMALL_STD),
+        "class_embed": ((cfg["num_classes"] + 1, d), SMALL_STD),
+    }
+    return tree
+
+
+def per_eval(cfg: dict) -> Dict[str, float]:
+    """MACs of one denoiser evaluation of one image: ``int8_macs``, the
+    projection GEMMs the served model quantizes (patch, timestep MLP, per
+    block q/k/v/o and the MLP, the final projection), and ``float_macs``,
+    the GEMMs it runs in floating point (attention scores and mixing, the
+    adaLN modulation dots). Matches the DiT count of the program's
+    ``perfmodel.flops.gemm_macs_per_model_eval``."""
+    d, f, n_layers = cfg["d_model"], cfg["d_ff"], cfg["n_layers"]
+    t = (cfg["latent_size"] // cfg["patch_size"]) ** 2
+    pdim = cfg["patch_size"] ** 2 * cfg["latent_channels"]
+
+    block_int8 = t * (4 * d * d + 2 * d * f)
+    block_float = 2 * t * t * d + 6 * d * d          # attention + modulation
+    embed_int8 = t * pdim * d + 256 * d + d * d + t * d * pdim
+    return {"int8_macs": float(n_layers * block_int8 + embed_int8),
+            "float_macs": float(n_layers * block_float + 2 * d * d)}
 
 
 def _site_id(name: str) -> int:

@@ -19,19 +19,19 @@ import gc
 import shutil
 import sys
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
 
-from chipbench import compare, drive, peaks as peaks_lib, spec, stats
-from chipbench import trace as trace_lib, traffic, weights
+from chipbench import attribute, compare, drive, peaks as peaks_lib, spec
+from chipbench import stats, trace as trace_lib, traffic, weights
 
 # Model fields the served program's configuration must share with the file.
 MODEL_KEYS = ("n_layers", "d_model", "n_heads", "d_ff", "latent_size",
               "latent_channels", "patch_size", "num_classes", "cond_dim",
               "cond_tokens")
-GAP_SPANS = ("submit", "engine.run", "collect")
+GAP_SPANS = ("submit", "engine.run", "collect", "wait")
 OUT_DIR = spec.REPO_ROOT / ".chipbench"
 
 
@@ -47,6 +47,9 @@ class Run:
     engine_padded_slots: int
     peaks: Optional[dict]
     trace: Optional[trace_lib.Summary]
+    # the traced window's device time by program scope, module and host
+    # phase (``chipbench.attribute``)
+    scopes: Optional[attribute.Scopes] = None
 
     @property
     def window_images(self) -> int:
@@ -102,6 +105,21 @@ def build_engine(model: dict, request: dict, engine_seed: int):
                          f"{eng.nominal_steps} nominal steps, the "
                          f"configuration {serving['nominal_steps']}")
     return eng
+
+
+def _top(busy: Dict[str, float]) -> List:
+    return [[k, v] for k, v in sorted(busy.items(), key=lambda kv: -kv[1])]
+
+
+def read_trace(trace_dir) -> Tuple[trace_lib.Summary, attribute.Scopes]:
+    """The window's summary (busy time, top ops, named idle gaps) and its
+    device time by scope, module and host phase, from one reading of the
+    ``.xplane.pb`` under ``trace_dir``."""
+    att = attribute.read(trace_lib.xplane_file(str(trace_dir)))
+    tr = att.trace()
+    window = trace_lib.find_span(tr, "window")
+    return (trace_lib.reduce(tr, window, GAP_SPANS),
+            attribute.window_scopes(att, window))
 
 
 class CompileCounter:
@@ -166,7 +184,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
                          clock=clock)
     counter.armed = False
     if trace:
+        t_stop = time.monotonic()
         jax.profiler.stop_trace()
+        log(f"profiler stopped in {time.monotonic() - t_stop:.3f}s")
     if counter.events or eng.cache.traces != traces0:
         raise RuntimeError(f"compiled inside the window: "
                            f"{len(counter.events)} compile events "
@@ -180,9 +200,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     log(f"window {record.window_s:.3f}s, {len(record.batches)} batches "
         f"({sum(b.in_window for b in record.batches)} in the window), "
         f"{record.window_images()} images; {len(due)} requests due in the "
-        f"window (latency samples); generator late by 0s (closed loop: "
-        f"each client resends as its request completes); 0 compiles in "
-        f"the window")
+        f"window (latency samples); generator late by "
+        f"{record.late_s:.6f}s ({mix['arrival']}); 0 compiles in the "
+        f"window")
 
     run = Run(cell=cell, model=model, record=record, bucket=bucket,
               steps=steps, engine_batches=eng.stats.batches - batches0,
@@ -194,9 +214,13 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
                    "memory_peak_bytes": mem.get("peak_bytes_in_use")}
     breakdown = None
     if trace:
-        tr = trace_lib.read_xplane(trace_lib.xplane_file(str(trace_dir)))
-        window = trace_lib.find_span(tr, "window")
-        run.trace = trace_lib.reduce(tr, window, GAP_SPANS)
+        t_read = time.monotonic()
+        run.trace, run.scopes = read_trace(trace_dir)
+        log(f"trace read in {time.monotonic() - t_read:.3f}s")
+        log(f"busy s by scope {_top(run.scopes.by_scope)}; by module "
+            f"{_top(run.scopes.by_module)}; idle in batch phases "
+            f"{run.scopes.phase_idle_s!r}s over {run.scopes.batches} "
+            f"batches")
         run.peaks = peaks_lib.for_kind(device.device_kind)
         shutil.rmtree(trace_dir, ignore_errors=True)
         device_info.update(busy_s=run.trace.busy_s,

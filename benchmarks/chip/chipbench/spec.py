@@ -8,8 +8,13 @@ its own under this benchmark's directory:
     limits/<workload>.json    the correctness limits and their readings
 
 and each per-layer metric is a reader module ``chipbench/metrics/<name>.py``
-(dots in a metric's name become underscores). A later change adds a cell,
-a mix or a metric by adding such files and entries, never by editing code.
+(dots in a metric's name become underscores). A configuration's
+``"reference"`` names its module ``chipbench/<reference>.py``, which states
+the architecture: ``param_shapes(cfg)``, the served tree the weights are
+drawn for; ``per_eval(cfg)``, the operations of one denoiser evaluation;
+and the plain reference ``correct`` compares with (``Precision``, ``Batch``,
+``sample``). A later change adds a cell, a mix, a metric or an
+architecture by adding such files and entries, never by editing code.
 """
 from __future__ import annotations
 

@@ -1,63 +1,22 @@
-"""Random DiT weights from a seed, made on the device in one call.
+"""Random weights from a seed, made on the device in one call.
 
-The tree has the layout the served model reads (``params["blocks"]``
-stacked over depth, as ``models/dit.py`` keeps it) and is built here from the
-configuration file's sizes alone. Every leaf but the position embedding is
-drawn, the adaLN-Zero modulation and output projections and the biases
-included: zero-initialised modulation makes every block an identity and the
-predicted noise exactly zero, which no trained checkpoint has. The position
-embedding is DiT's frozen 2-D sine-cosine table (arXiv:2212.09748, Sec. 3.2),
-as every DiT checkpoint carries it: a small drawn one leaves each token's
-state to the image alone, and the sampler then amplifies float rounding
-into the finals as no trained model does. Leaves are float32, the type the
-model serves its parameters in.
+The tree is the one the configuration's reference module states
+(``param_shapes(cfg) -> {leaf path: (shape, std or SINCOS)}``), so a new
+architecture brings its tree with its reference and edits nothing here.
+Each leaf is drawn from a normal distribution of its std, or is the frozen
+2-D sine-cosine position table (``SINCOS``). Leaves are float32, the type
+the models serve their parameters in.
 """
 from __future__ import annotations
 
 import math
-from typing import Dict
 
 import jax
 import jax.numpy as jnp
 
-# Scale of the leaves a trained DiT starts from zero (adaLN, output
-# projection, biases) and of the class embedding.
-SMALL_STD = 0.02
+from chipbench import spec
+
 SINCOS = "sincos"       # in place of a std: the fixed position table
-
-
-def shapes(cfg: dict) -> Dict:
-    """{leaf path: (shape, std)} of the served tree."""
-    d, f, n_layers = cfg["d_model"], cfg["d_ff"], cfg["n_layers"]
-    tokens = (cfg["latent_size"] // cfg["patch_size"]) ** 2
-    pdim = cfg["patch_size"] ** 2 * cfg["latent_channels"]
-
-    def dense(k, n):
-        return ((k, n), 1.0 / math.sqrt(k))
-
-    def stacked(leaf):
-        shape, std = leaf
-        return ((n_layers,) + shape, std)
-
-    attn = {"wq": stacked(dense(d, d)), "wk": stacked(dense(d, d)),
-            "wv": stacked(dense(d, d)), "wo": stacked(dense(d, d))}
-    blocks = {"adaln_w": stacked(((d, 6 * d), SMALL_STD)),
-              "adaln_b": stacked(((6 * d,), SMALL_STD)),
-              "attn": attn,
-              "mlp_w1": stacked(dense(d, f)),
-              "mlp_w2": stacked(dense(f, d))}
-    tree = {
-        "patch_w": dense(pdim, d), "patch_b": ((d,), SMALL_STD),
-        "pos_embed": ((tokens, d), SINCOS),
-        "t_w1": dense(256, d), "t_b1": ((d,), SMALL_STD),
-        "t_w2": dense(d, d), "t_b2": ((d,), SMALL_STD),
-        "blocks": blocks,
-        "final_adaln_w": ((d, 2 * d), SMALL_STD),
-        "final_adaln_b": ((2 * d,), SMALL_STD),
-        "final_w": ((d, pdim), SMALL_STD), "final_b": ((pdim,), SMALL_STD),
-        "class_embed": ((cfg["num_classes"] + 1, d), SMALL_STD),
-    }
-    return tree
 
 
 def _is_leaf(x) -> bool:
@@ -82,8 +41,8 @@ def sincos_2d(tokens: int, d: int) -> jax.Array:
 def make(cfg: dict, seed: int):
     """The weights for ``seed``: one jitted call, leaves on the default
     device in float32."""
-    spec = shapes(cfg)
-    leaves, treedef = jax.tree.flatten(spec, is_leaf=_is_leaf)
+    tree = spec.reference_module(cfg).param_shapes(cfg)
+    leaves, treedef = jax.tree.flatten(tree, is_leaf=_is_leaf)
 
     def leaf(k, shape, std):
         if std == SINCOS:

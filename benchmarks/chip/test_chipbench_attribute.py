@@ -1,10 +1,12 @@
 """CPU tests of the attribution of device time to the program's modules,
-scopes and host phases (``chipbench.attribute``), on hand-built traces."""
+scopes and host phases (``chipbench.attribute``), and of the per-layer
+readers that take their numbers from it, on hand-built traces."""
 from __future__ import annotations
 
 import os
 import subprocess
 import sys
+import types
 from pathlib import Path
 
 import pytest
@@ -13,7 +15,7 @@ HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 
 from chipbench import attribute as att_lib  # noqa: E402
-from chipbench import trace as trace_lib  # noqa: E402
+from chipbench import spec, trace as trace_lib  # noqa: E402
 
 Op = att_lib.Op
 DRIFT, TWIN = "jit_sample_drift", "jit_sample_clean"
@@ -149,9 +151,21 @@ def _attributed():
                               host_spans=host)
 
 
+# the readers of the attributed trace, by metric name
+SCOPE_READERS = ("attention_ms_per_image_step", "gemm_ms_per_image_step",
+                 "checksum_ms_per_image_step", "rollback_ms_per_image_step",
+                 "twin_share", "engine_idle_ms_per_batch")
+
+
+def _read_all(att, window, images, steps):
+    run = types.SimpleNamespace(scopes=att_lib.window_scopes(att, window),
+                                window_images=images, steps=steps)
+    return {name: spec.metric_reader(name)(run) for name in SCOPE_READERS}
+
+
 def test_metrics_on_a_hand_built_trace():
     att = _attributed()
-    m = att_lib.metrics(att, (0, 1000), images=2, steps=5)
+    m = _read_all(att, (0, 1000), images=2, steps=5)
     busy = 400 + 200 + 60 + 50 + 50          # union of ops in the window
     assert att_lib.busy_s(att, (0, 1000)) == pytest.approx(busy * 1e-9)
     assert m["twin_share"] == pytest.approx(100 * 260 / busy)
@@ -159,11 +173,24 @@ def test_metrics_on_a_hand_built_trace():
     assert m["engine_idle_ms_per_batch"] == pytest.approx(
         1e3 * (50 + 50 + 40 + 100) * 1e-9 / 2)
     per = 1e3 * 1e-9 / (2 * 5)
-    assert m["inject_ms_per_image_step"] == pytest.approx((100 + 50) * per)
+    assert m["gemm_ms_per_image_step"] is None        # no drift.gemm op
+    gemm = att_lib.Attributed(
+        device_ops={p: [o._replace(scope="drift.gemm")
+                        if o.label == "fusion h" else o for o in ops]
+                    for p, ops in att.device_ops.items()},
+        host_spans=att.host_spans)
+    assert _read_all(gemm, (0, 1000), images=2, steps=5)[
+        "gemm_ms_per_image_step"] == pytest.approx(60 * per)
     assert m["checksum_ms_per_image_step"] == pytest.approx((150 + 200)
                                                             * per)
-    assert m["rollback_ms_per_image_step"] == pytest.approx(70 * per)
+    # by scope, then summed: correct [250,300) and ckpt [280,320) overlap
+    # here, which ops of one core never do
+    assert m["rollback_ms_per_image_step"] == pytest.approx((50 + 40) * per)
     assert m["attention_ms_per_image_step"] == pytest.approx(80 * per)
+    scopes = att_lib.window_scopes(att, (0, 1000))
+    assert scopes.by_scope["drift.inject"] == pytest.approx(150e-9)
+    assert scopes.by_module["jit_clip"] == pytest.approx(50e-9)
+    assert scopes.batches == 2
 
 
 def test_breakdown_charges_scopes_and_eager_modules():
@@ -178,19 +205,35 @@ def test_breakdown_charges_scopes_and_eager_modules():
 
 def test_metrics_return_none_where_nothing_is_named():
     """A program without scopes, mode names or phase spans (the parent of
-    this change) reads None for every metric; an empty trace reads
-    nothing."""
+    PR 13's naming) reads None for every metric; so does an untraced run,
+    a clean cell's sampler (no twin beside a drift sampler), and a window
+    that served no image."""
     bare = att_lib.Attributed(
         device_ops={"/device:TPU:0": [Op("fusion a", 0, 100, "jit__run",
                                          "other")]},
         host_spans=[("window", 0, 200), ("engine.run", 0, 200)])
-    m = att_lib.metrics(bare, (0, 200), images=4, steps=20)
-    assert set(m) == {"twin_share", "engine_idle_ms_per_batch",
-                      *att_lib.SCOPE_METRICS}
-    assert all(v is None for v in m.values())
-    assert att_lib.metrics(att_lib.Attributed({}, []), (0, 1), 4, 20) == {}
-    assert att_lib.metrics(_attributed(), (0, 1000), images=0,
-                           steps=20)["inject_ms_per_image_step"] is None
+    m = _read_all(bare, (0, 200), images=4, steps=20)
+    assert set(m) == set(SCOPE_READERS)
+    assert all(v is None for v in m.values()), m
+    untraced = types.SimpleNamespace(scopes=None, window_images=4, steps=20)
+    assert all(spec.metric_reader(n)(untraced) is None
+               for n in SCOPE_READERS)
+    clean = att_lib.Attributed(
+        device_ops={"/device:TPU:0": [
+            Op("fusion a", 0, 100, TWIN, "dit.attention"),
+            Op("fusion b", 100, 150, TWIN, "drift.checksum")]},
+        host_spans=[("window", 0, 200), ("batch.prepare", 0, 1)])
+    m = _read_all(clean, (0, 200), images=4, steps=20)
+    assert m["twin_share"] is None
+    assert m["attention_ms_per_image_step"] == pytest.approx(
+        1e3 * 100e-9 / 80)
+    assert m["rollback_ms_per_image_step"] is None
+    assert m["gemm_ms_per_image_step"] is None
+    assert _read_all(_attributed(), (0, 1000), images=0, steps=20)[
+        "checksum_ms_per_image_step"] is None
+    # a scope whose ops all lie outside the window is absent, not 0
+    assert _read_all(_attributed(), (0, 240), images=2, steps=5)[
+        "attention_ms_per_image_step"] is None
 
 
 def test_attributed_trace_reduces_as_the_plain_trace():
@@ -235,3 +278,81 @@ print("ok")
                          env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr[-2000:]
     assert out.stdout.strip().endswith("ok")
+
+
+def test_runner_builds_the_same_summary_from_the_attributed_trace(
+        monkeypatch):
+    """``runner.read_trace`` reads the trace once, through
+    ``attribute.read``; its summary is the one that ``trace.reduce`` gives
+    on the plain trace (``read_xplane``'s, which ``Attributed.trace``
+    equals: ``test_read_matches_read_xplane_on_a_cpu_trace``), and its
+    scopes are ``window_scopes`` of the window."""
+    from chipbench import runner
+    att = _attributed()
+    plain = trace_lib.Trace(
+        device_ops={p: [(o.label, o.start, o.end) for o in ops]
+                    for p, ops in att.device_ops.items()},
+        host_spans=list(att.host_spans))
+    monkeypatch.setattr(trace_lib, "xplane_file", lambda d: f"{d}/t.pb")
+    monkeypatch.setattr(att_lib, "read", lambda path: att)
+    summary, scopes = runner.read_trace("trace-dir")
+    window = trace_lib.find_span(plain, "window")
+    assert summary == trace_lib.reduce(plain, window, runner.GAP_SPANS)
+    assert scopes == att_lib.window_scopes(att, window)
+    assert "wait" in runner.GAP_SPANS
+
+
+def _idle_in_spans_reference(att, window, spans):
+    """Device idle inside ``spans``, span by span over every busy interval
+    (the reading ``window_scopes`` replaced with one merged pass)."""
+    lo, hi = window
+    inside = trace_lib.union(trace_lib.clip(spans, lo, hi))
+    planes = att_lib._planes(att)
+    total = 0.0
+    for ops in planes:
+        busy = trace_lib.union(trace_lib.clip(
+            [(o.start, o.end) for o in ops], lo, hi))
+        for s, e in inside:
+            total += sum(b - a for a, b in trace_lib.gaps(
+                trace_lib.clip(busy, s, e), s, e))
+    return total * 1e-9 / max(len(planes), 1)
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_window_scopes_in_one_pass_match_the_unions(seed):
+    """On random traces of two devices, with overlapping and unsorted ops,
+    asynchronous starts and ops crossing the window's edges, the one-pass
+    reading equals the per-key unions of ``busy_by`` and the span-by-span
+    idle time."""
+    import random
+    rng = random.Random(seed)
+    scopes = ["drift.gemm", "drift.checksum", "dit.attention", "other"]
+    device_ops, host = {}, [("window", 500, 90_000)]
+    for d in range(2):
+        ops, t = [], 0
+        while t < 100_000:
+            dur = rng.randint(1, 400)
+            label = rng.choice(["fusion a", "reduce b", "copy-start c"])
+            ops.append(Op(label, t, t + dur, rng.choice([DRIFT, TWIN, ""]),
+                          rng.choice(scopes)))
+            t += rng.randint(-100, 500)
+        rng.shuffle(ops)
+        device_ops[f"/device:TPU:{d}"] = ops
+    t = 0
+    while t < 100_000:
+        n = rng.randint(50, 3000)
+        host.append((rng.choice(["batch.prepare", "batch.sample", "submit"]),
+                     t, t + n))
+        t += n + rng.randint(0, 2000)
+    att = att_lib.Attributed(device_ops=device_ops, host_spans=host)
+    window = (500, 90_000)
+    got = att_lib.window_scopes(att, window)
+    assert got.busy_s == pytest.approx(att_lib.busy_s(att, window))
+    for kind, key in (("by_scope", lambda o: o.scope),
+                      ("by_module", lambda o: o.module)):
+        want = att_lib.busy_by(att, window, key)
+        assert getattr(got, kind) == pytest.approx(want), kind
+    assert got.phase_idle_s == pytest.approx(_idle_in_spans_reference(
+        att, window, att_lib.phase_spans(att, window)))
+    assert got.batches == len(att_lib.phase_spans(att, window,
+                                                  "batch.prepare"))

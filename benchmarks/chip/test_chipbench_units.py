@@ -1,8 +1,10 @@
 """CPU tests of the benchmark's yardstick: trace reduction, operation
-counts, traffic generation, the engine-driving loop and the loading of
-files by name. None of them needs an accelerator."""
+counts, the weights and counts a configuration's reference module states,
+traffic generation, the engine-driving loops and the loading of files by
+name. None of them needs an accelerator."""
 from __future__ import annotations
 
+import hashlib
 import json
 import subprocess
 import sys
@@ -191,6 +193,90 @@ def test_opcount_matches_perfmodel_for_dit():
         pytest.approx(want, rel=1e-12)
 
 
+# the parent's DiT trees and weights, recorded before the tree moved into
+# the reference module: sha256 of the (path, shape, std) listing, and of the
+# smoke weights' paths and bytes at two seeds
+TREE_DIGESTS = {
+    "testdata/dit-smoke.json":
+        "6c2410ef404c7aa60be502422c451d42fa09883c7ef2348671e6ada2fb3f43af",
+    "configs/dit-xl-512.json":
+        "d3c46dbaacc929a800bf256c9010f87466f6d026c4bb0c3c9a00d35a34189733",
+}
+WEIGHT_DIGESTS = {
+    0: "8f2a4822d9730ea902cb2a9ede01de440161acbb77eefaba2db37bc68b5058bf",
+    2 ** 31 + 77:
+        "f558733c58d4aa3725ce268ac2413a519e6f8f94312573dd70d4e7fab88a5c7c",
+}
+
+
+def _tree_listing(tree):
+    import jax
+    from chipbench import weights
+    flat = jax.tree_util.tree_flatten_with_path(tree,
+                                                is_leaf=weights._is_leaf)[0]
+    return [[jax.tree_util.keystr(p), list(shape), std]
+            for p, (shape, std) in flat]
+
+
+@pytest.mark.parametrize("path", sorted(TREE_DIGESTS))
+def test_dit_tree_keeps_its_leaf_paths_shapes_and_stds(path):
+    cfg = spec.load_json(HERE / path)
+    listing = _tree_listing(spec.reference_module(cfg).param_shapes(cfg))
+    assert len(listing) == 20
+    digest = hashlib.sha256(json.dumps(listing).encode()).hexdigest()
+    assert digest == TREE_DIGESTS[path], listing
+
+
+@pytest.mark.parametrize("seed", sorted(WEIGHT_DIGESTS))
+def test_dit_smoke_weights_match_the_recorded_digest(seed):
+    import jax
+    from chipbench import weights
+    cfg = spec.load_json(HERE / "testdata" / "dit-smoke.json")
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            weights.make(cfg, seed))[0]:
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(np.asarray(leaf).tobytes())
+    assert h.hexdigest() == WEIGHT_DIGESTS[seed]
+
+
+def test_a_stand_in_reference_module_needs_no_harness_edit(monkeypatch):
+    """Another architecture's tree and count reach ``weights.make``,
+    ``opcount`` and ``mfu`` through its reference module alone."""
+    import jax
+    from chipbench import weights
+    from chipbench.metrics import mfu
+    cfg = {"name": "stand-in", "reference": "ref_stand_in", "d": 8}
+    stand_in = types.SimpleNamespace(
+        param_shapes=lambda c: {
+            "caption": {"w1": ((16, c["d"]), 0.5), "w2": ((c["d"],), 0.0)},
+            "xattn": {"wq": ((2, c["d"], c["d"]), 1.0)},
+            "pos": ((4, c["d"]), weights.SINCOS)},
+        per_eval=lambda c: {"int8_macs": 3e9, "float_macs": 1e9})
+    asked = []
+
+    def reference_module(config):
+        asked.append(config["reference"])
+        return stand_in
+    monkeypatch.setattr(spec, "reference_module", reference_module)
+    params = weights.make(cfg, 2 ** 31 + 3)
+    assert sorted(params) == ["caption", "pos", "xattn"]
+    assert params["caption"]["w1"].shape == (16, 8)
+    assert params["xattn"]["wq"].shape == (2, 8, 8)
+    assert not np.any(np.asarray(params["caption"]["w2"]))
+    np.testing.assert_array_equal(params["pos"], weights.sincos_2d(4, 8))
+    assert all(leaf.dtype == np.float32 for leaf in jax.tree.leaves(params))
+    v5e = peaks.for_kind("TPU v5 lite")
+    roof = 2 * 3e9 / v5e["int8_ops_per_s"] + 2 * 1e9 / v5e["bf16_flops_per_s"]
+    assert opcount.roofline_s_per_eval(cfg, v5e) == pytest.approx(roof)
+    summary = trace_lib.Summary(window_s=2.0, busy_s=1.0, op_seconds=[],
+                                gaps=[])
+    run = types.SimpleNamespace(trace=summary, window_images=3, steps=10,
+                                model=cfg, peaks=v5e)
+    assert mfu.read(run) == pytest.approx(100 * 3 * 10 * roof / 2.0)
+    assert set(asked) == {"ref_stand_in"}
+
+
 def test_peaks_by_device_kind():
     v5e = peaks.for_kind("TPU v5 lite")
     assert v5e["bf16_flops_per_s"] == 197e12
@@ -236,10 +322,15 @@ class _FakeEngine:
 
 class _Clock:
     def __init__(self):
-        self.t = 100.0
+        self.t, self.slept = 100.0, 0.0
 
     def __call__(self):
         return self.t
+
+    def sleep(self, s):
+        assert s >= 0
+        self.t += s
+        self.slept += s
 
 
 def test_closed_loop_two_batches_outstanding():
@@ -265,10 +356,141 @@ def test_closed_loop_two_batches_outstanding():
 
 
 def test_closed_loop_refuses_other_arrivals():
-    with pytest.raises(ValueError):
-        traffic.clients({"arrival": "poisson", "clients": 8})
+    """An arrival process that no generator knows is refused, before any
+    request is sent; so is a closed loop without clients."""
+    bursty = {"arrival": "bursty", "clients": 8, "rate_per_s": 1.0,
+              "request": {}}
+    for ask in (traffic.arrival, traffic.clients, traffic.rate):
+        with pytest.raises(ValueError, match="unknown arrival"):
+            ask(bursty)
+    clock = _Clock()
+    with pytest.raises(ValueError, match="unknown arrival"):
+        drive.drive(_FakeEngine(clock, 1.0), bursty, {}, 4, seed=1,
+                    seconds=3.0, keep_twin=False, clock=clock)
     with pytest.raises(ValueError):
         traffic.clients({"arrival": "closed", "clients": 0})
+    with pytest.raises(ValueError):
+        traffic.rate({"arrival": "poisson", "rate_per_s": 0})
+
+
+# ------------------------------------------------------------ open loop
+POISSON = {"arrival": "poisson", "rate_per_s": 1.6,
+           "request": {"mode": "clean", "op": "undervolt"}}
+
+
+def test_poisson_due_times_repeat_by_seed():
+    def offsets(seed):
+        return traffic.arrival_offsets(seed, 1.6, 51.0)
+    a, b, c = offsets(2 ** 31 + 77), offsets(2 ** 31 + 77), offsets(5)
+    assert a == b and a != c
+    assert a[0] == 0.0 and all(y > x for x, y in zip(a, a[1:]))
+    # every seed offers the same number of requests in the window
+    assert {len(offsets(s)) for s in (1, 5, 2 ** 31 + 77, 2 ** 32 + 9)} \
+        == {82}
+    assert a[-1] < 51.0
+    # the requests are the closed loop's, in the same order
+    seeds = [s for _, s in zip(range(10), traffic.request_seeds(9))]
+    clock = _Clock()
+    rec = drive.drive(_FakeEngine(clock, 1.0), POISSON, {}, 4, seed=9,
+                      seconds=4.0, keep_twin=False, clock=clock,
+                      sleep=clock.sleep)
+    assert [r.seed for r in rec.requests] == seeds[:len(rec.requests)]
+
+
+def test_poisson_mean_rate_over_ten_thousand_draws():
+    for seed, rate in ((2 ** 31 + 9, 1.6), (3, 0.25), (4, 40.0)):
+        t = traffic.arrival_offsets(seed, rate, 10_000 / rate)
+        assert len(t) == 10_000
+        gaps = np.diff(t)
+        assert 1 / gaps.mean() == pytest.approx(rate, rel=0.05)
+        # about exponential: the standard deviation equals the mean, and
+        # a gap exceeds the mean with probability 1/e
+        assert gaps.std() == pytest.approx(gaps.mean(), rel=0.05)
+        assert np.mean(gaps > 1 / rate) == pytest.approx(np.exp(-1),
+                                                         rel=0.05)
+
+
+def test_open_loop_waits_and_dispatches_partial_buckets():
+    clock = _Clock()
+    eng = _FakeEngine(clock, 1.0)
+    rate = 1.6
+    rec = drive.drive(eng, POISSON, {}, 4, seed=21, seconds=30.0,
+                      keep_twin=False, clock=clock, sleep=clock.sleep)
+    offsets = traffic.arrival_offsets(21, rate, 30.0)
+    due = [r.due - rec.t0 for r in rec.requests]
+    assert due == pytest.approx(offsets)
+    # the engine was handed only requests already due, up to a bucket
+    for b in rec.batches:
+        assert 1 <= len(b.seeds) <= 4
+        assert len(b.padded) == 4 and b.padded[len(b.seeds):] == \
+            (b.seeds[-1],) * (4 - len(b.seeds))
+    for r in rec.requests:
+        assert r.dispatched >= r.due
+    assert any(len(b.seeds) < 4 for b in rec.batches)
+    # it waited when nothing was due: the engine ran less than the window
+    assert sum(b.done - b.dispatched for b in rec.batches) < rec.window_s
+    assert clock.slept > 0 and rec.late_s == 0
+    # a request is handed over when the engine frees or when it falls due
+    frees = {b.done for b in rec.batches} | {rec.t0}
+    for r in rec.requests:
+        assert r.dispatched == r.due or r.dispatched in frees
+
+
+def test_open_loop_drains_and_counts_due_requests():
+    clock = _Clock()
+    # batches of 3 s against 1.6 requests/s: the queue grows
+    eng = _FakeEngine(clock, 3.0)
+    rec = drive.drive(eng, POISSON, {}, 4, seed=2 ** 31 + 1, seconds=10.0,
+                      keep_twin=False, clock=clock, sleep=clock.sleep)
+    offsets = traffic.arrival_offsets(2 ** 31 + 1, 1.6, 10.0)
+    in_window = [b for b in rec.batches if b.in_window]
+    # dispatched at 0, 3, 6, 9: the window closes at 12, past its 10 s
+    assert [b.dispatched - rec.t0 for b in in_window] == [0, 3, 6, 9]
+    assert rec.window_s == pytest.approx(12.0)
+    due_by_close = sum(t < rec.window_s for t in offsets)
+    assert len(rec.due_in_window()) == due_by_close == len(rec.requests)
+    assert all(np.isfinite(r.done) for r in rec.requests)
+    assert rec.window_images() == sum(len(b.seeds) for b in in_window)
+    assert not rec.batches[-1].in_window
+    # the engine was never idle while requests were due
+    assert clock.slept == 0
+
+
+def test_open_loop_window_ends_at_its_length_when_idle():
+    clock = _Clock()
+    eng = _FakeEngine(clock, 0.1)
+    rec = drive.drive(eng, {**POISSON, "rate_per_s": 0.2}, {}, 4, seed=4,
+                      seconds=20.0, keep_twin=False, clock=clock,
+                      sleep=clock.sleep)
+    assert rec.window_s == pytest.approx(20.0)
+    assert all(b.in_window for b in rec.batches)
+    assert len(rec.due_in_window()) == len(rec.requests)
+
+
+def test_a_poisson_cell_loads_by_name(tmp_path):
+    """A cell with an open-loop mix is a data file and an entry: it loads
+    through ``spec.load_cell`` and drives the open loop unedited."""
+    bench = dict(BENCH)
+    cell = {"name": "dit-xl-512.clean-poisson", "config": "dit-xl-512",
+            "traffic": "clean-poisson", "chips": 1, "why": "test"}
+    bench["workloads"] = [cell]
+    (tmp_path / "traffic").mkdir()
+    (tmp_path / "configs").mkdir()
+    (tmp_path / "traffic" / "clean-poisson.json").write_text(
+        json.dumps(POISSON))
+    (tmp_path / "configs" / "dit-xl-512.json").write_text(
+        (HERE / "configs" / "dit-xl-512.json").read_text())
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    loaded = spec.load_cell(cell["name"], tmp_path / "BENCHMARK.json",
+                            tmp_path)
+    assert loaded.traffic == POISSON
+    assert traffic.arrival(loaded.traffic) == "poisson"
+    assert traffic.rate(loaded.traffic) == 1.6
+    clock = _Clock()
+    rec = drive.drive(_FakeEngine(clock, 1.0), loaded.traffic, {}, 4,
+                      seed=7, seconds=5.0, keep_twin=False, clock=clock,
+                      sleep=clock.sleep)
+    assert rec.batches and rec.requests
 
 
 def test_percentiles():
@@ -330,15 +552,16 @@ def test_weights_tree_matches_the_served_layout():
 
 def test_imports_start_no_backend():
     """Importing the harness initialises no JAX backend (so no TPU)."""
+    names = [m["name"] for m in BENCH["per_layer"]]
     code = f"""
 import sys
 sys.path.insert(0, {str(HERE)!r})
 import runpy
 import chipbench.runner, chipbench.compare, chipbench.ref_dit
 import chipbench.weights, chipbench.trace
-for m in ("queue_wait_p90_s", "bucket_fill", "device_ms_per_image_step",
-          "mfu", "device_idle_share"):
-    __import__("chipbench.metrics." + m)
+from chipbench import spec
+for m in {names!r}:
+    spec.metric_reader(m)
 from jax._src import xla_bridge
 assert not xla_bridge._backends, xla_bridge._backends
 print("ok")
